@@ -146,9 +146,8 @@ class FaultyEndpoint:
     def compiled(self) -> bool:
         return self._inner.compiled
 
-    @property
-    def resident(self) -> bool:
-        return self._inner.resident
+    def resident_on(self, dev_id: int = 0) -> bool:
+        return self._inner.resident_on(dev_id)
 
     @property
     def weight_bytes(self) -> int:
@@ -162,19 +161,19 @@ class FaultyEndpoint:
     def last_use(self, v) -> None:
         self._inner.last_use = v
 
-    def compile(self) -> None:
-        self._inner.compile()
+    def compile(self, dev_id: int = 0) -> None:
+        self._inner.compile(dev_id)
 
-    def upload(self) -> None:
-        self._inner.upload()
+    def upload(self, dev_id: int = 0) -> None:
+        self._inner.upload(dev_id)
 
-    def evict(self) -> None:
-        self._inner.evict()
+    def evict(self, dev_id: int = 0) -> None:
+        self._inner.evict(dev_id)
 
-    def execute(self, request=None):
+    def execute(self, request=None, dev_id: int = 0):
         f = self._injector.next_endpoint_fault(self.fn_id)
         if f is not None:
             if f.mode == "hang" and f.latency > 0.0:
                 time.sleep(f.latency)
             raise FaultError(self.fn_id, f.mode)
-        return self._inner.execute(request)
+        return self._inner.execute(request, dev_id)

@@ -9,25 +9,44 @@ cache tile is read once per kv head, not once per q head — the G-fold
 arithmetic-intensity win GQA exists for.
 
 Supports full caches (valid length = pos+1) and ring-buffer caches
-(sliding window): masking is by slot *positions*, provided per tile.
+(sliding window): masking is by slot *positions*, streamed as a (1, bs)
+VMEM block per tile next to the cache tile (SMEM holds only the query
+position: Mosaic loads scalars, not vectors, from SMEM).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (>= 0.6); support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
+def _valid(pos, k_pos, window: int):
+    """(1, bs) mask of the slots the query at ``pos`` attends to; slot
+    position -1 marks a slot never written."""
+    valid = (k_pos >= 0) & (k_pos <= pos)
+    if window:
+        valid &= k_pos > pos - window
+    return valid
+
+
+def _pad_slots(slot_pos, S: int, bs: int):
+    """Slot positions as a (1, S_padded) int32 row, padded with -1."""
+    pad = (-S) % bs
+    slot_pos = slot_pos.astype(jnp.int32)
+    if pad:
+        slot_pos = jnp.pad(slot_pos, (0, pad), constant_values=-1)
+    return slot_pos[None, :]
+
+
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
                    m_ref, l_ref, acc_ref, *,
                    bs: int, window: int, scale: float):
     si = pl.program_id(1)
@@ -45,12 +64,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale    # (G, bs)
 
-    pos = pos_ref[0]                 # query position (scalar prefetch)
-    k_pos = pos_ref[pl.ds(1 + si * bs, bs)]            # slot positions
-    valid = (k_pos >= 0) & (k_pos <= pos)
-    if window:
-        valid &= k_pos > pos - window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(_valid(pos_ref[0], sp_ref[...], window), s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -69,7 +83,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention_bhd(q, k, v, pos, slot_pos, *, window: int = 0,
-                         bs: int = 512, interpret: bool = True):
+                         bs: int = 512, interpret: Optional[bool] = None):
     """q: (BH, G, dh) one token per kv-head row; k/v: (BH, S, dh);
     pos: scalar int32 query position; slot_pos: (S,) int32 absolute
     positions stored in each cache slot (-1 = never written)."""
@@ -80,12 +94,9 @@ def decode_attention_bhd(q, k, v, pos, slot_pos, *, window: int = 0,
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-        slot_pos = jnp.pad(slot_pos, (0, pad), constant_values=-1)
+    slots = _pad_slots(slot_pos, S, bs)
     ns = k.shape[1] // bs
-
-    # scalar-prefetch operand: [pos, slot_pos...]
-    meta = jnp.concatenate(
-        [jnp.asarray(pos, jnp.int32)[None], slot_pos.astype(jnp.int32)])
+    meta = jnp.asarray(pos, jnp.int32)[None]    # scalar prefetch: [pos]
 
     kernel = functools.partial(_decode_kernel, bs=bs, window=window,
                                scale=dh ** -0.5)
@@ -96,6 +107,7 @@ def decode_attention_bhd(q, k, v, pos, slot_pos, *, window: int = 0,
             pl.BlockSpec((1, G, dh), lambda b, j, meta: (b, 0, 0)),
             pl.BlockSpec((1, bs, dh), lambda b, j, meta: (b, j, 0)),
             pl.BlockSpec((1, bs, dh), lambda b, j, meta: (b, j, 0)),
+            pl.BlockSpec((1, bs), lambda b, j, meta: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, G, dh), lambda b, j, meta: (b, 0, 0)),
         scratch_shapes=[
@@ -108,10 +120,10 @@ def decode_attention_bhd(q, k, v, pos, slot_pos, *, window: int = 0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(meta, q, k, v)
+        interpret=resolve_interpret(interpret),
+    )(meta, q, k, v, slots)
     return out
 
 
@@ -120,11 +132,14 @@ def decode_attention_bhd(q, k, v, pos, slot_pos, *, window: int = 0,
 # Same flash-decoding loop, but the cache tiles arrive in VMEM as int8
 # plus one f32 scale per (slot, kv-head): HBM traffic for the dominant
 # operand is halved, and dequantization happens on-chip right before the
-# MXU dots. The online-softmax state and masking are identical to the
-# bf16 kernel.
+# MXU dots. The per-slot scales are applied to the (G, bs) score and
+# probability tiles rather than to the (bs, dh) cache tiles: a (1, bs)
+# row broadcasts over sublanes only, where a per-slot column would need
+# a lane-to-sublane relayout. The online-softmax state and masking are
+# identical to the bf16 kernel.
 
-def _decode_kernel_q8(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-                      m_ref, l_ref, acc_ref, *,
+def _decode_kernel_q8(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, sp_ref,
+                      o_ref, m_ref, l_ref, acc_ref, *,
                       bs: int, window: int, scale: float):
     si = pl.program_id(1)
     ns = pl.num_programs(1)
@@ -136,26 +151,20 @@ def _decode_kernel_q8(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0]                                       # (G, dh)
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, None]   # dequant (bs, dh)
+    k = k_ref[0].astype(jnp.float32)                   # (bs, dh)
     s = jax.lax.dot_general(
         q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (G, bs)
-
-    pos = pos_ref[0]
-    k_pos = pos_ref[pl.ds(1 + si * bs, bs)]
-    valid = (k_pos >= 0) & (k_pos <= pos)
-    if window:
-        valid &= k_pos > pos - window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+        preferred_element_type=jnp.float32) * (ks_ref[0] * scale)  # (G, bs)
+    s = jnp.where(_valid(pos_ref[0], sp_ref[...], window), s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None]   # dequant (bs, dh)
+    v = v_ref[0].astype(jnp.float32)                   # (bs, dh)
     acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        p * vs_ref[0], v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
@@ -167,7 +176,7 @@ def _decode_kernel_q8(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 
 def decode_attention_bhd_q8(q, k, k_scale, v, v_scale, pos, slot_pos, *,
                             window: int = 0, bs: int = 512,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """int8-cache decode. q: (BH, G, dh); k/v: (BH, S, dh) int8;
     k_scale/v_scale: (BH, S) f32 per-(slot, kv-head) scales."""
     BH, G, dh = q.shape
@@ -179,11 +188,12 @@ def decode_attention_bhd_q8(q, k, k_scale, v, v_scale, pos, slot_pos, *,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
         k_scale = jnp.pad(k_scale, ((0, 0), (0, pad)))
         v_scale = jnp.pad(v_scale, ((0, 0), (0, pad)))
-        slot_pos = jnp.pad(slot_pos, (0, pad), constant_values=-1)
+    slots = _pad_slots(slot_pos, S, bs)
     ns = k.shape[1] // bs
-
-    meta = jnp.concatenate(
-        [jnp.asarray(pos, jnp.int32)[None], slot_pos.astype(jnp.int32)])
+    # scales as (BH, 1, S): each tile's (1, bs) block spans its full
+    # sublane dim, as Mosaic requires of a block's last two dims
+    k_scale, v_scale = k_scale[:, None, :], v_scale[:, None, :]
+    meta = jnp.asarray(pos, jnp.int32)[None]
 
     kernel = functools.partial(_decode_kernel_q8, bs=bs, window=window,
                                scale=dh ** -0.5)
@@ -193,9 +203,10 @@ def decode_attention_bhd_q8(q, k, k_scale, v, v_scale, pos, slot_pos, *,
         in_specs=[
             pl.BlockSpec((1, G, dh), lambda b, j, meta: (b, 0, 0)),
             pl.BlockSpec((1, bs, dh), lambda b, j, meta: (b, j, 0)),
-            pl.BlockSpec((1, bs), lambda b, j, meta: (b, j)),
+            pl.BlockSpec((1, 1, bs), lambda b, j, meta: (b, 0, j)),
             pl.BlockSpec((1, bs, dh), lambda b, j, meta: (b, j, 0)),
-            pl.BlockSpec((1, bs), lambda b, j, meta: (b, j)),
+            pl.BlockSpec((1, 1, bs), lambda b, j, meta: (b, 0, j)),
+            pl.BlockSpec((1, bs), lambda b, j, meta: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, G, dh), lambda b, j, meta: (b, 0, 0)),
         scratch_shapes=[
@@ -208,8 +219,8 @@ def decode_attention_bhd_q8(q, k, k_scale, v, v_scale, pos, slot_pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(meta, q, k, k_scale, v, v_scale)
+        interpret=resolve_interpret(interpret),
+    )(meta, q, k, k_scale, v, v_scale, slots)
     return out

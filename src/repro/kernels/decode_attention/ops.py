@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ from repro.models.attention import ring_slot_positions
 @functools.partial(jax.jit,
                    static_argnames=("window", "ring", "interpret"))
 def decode_attention(q, cache_k, cache_v, pos, *, window: int = 0,
-                     ring: bool = False, interpret: bool = True):
+                     ring: bool = False, interpret: Optional[bool] = None):
     B, one, H, dh = q.shape
     S, KV = cache_k.shape[1], cache_k.shape[2]
     G = H // KV
@@ -33,7 +34,7 @@ def decode_attention(q, cache_k, cache_v, pos, *, window: int = 0,
                    static_argnames=("window", "ring", "interpret"))
 def decode_attention_quant(q, cache_k, k_scale, cache_v, v_scale, pos, *,
                            window: int = 0, ring: bool = False,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """Model-layout wrapper for the int8-cache kernel.
 
     q: (B,1,H,dh); cache_k/v: (B,S,KV,dh) int8; scales: (B,S,KV) f32."""

@@ -12,15 +12,14 @@ lane dims when the head_dim allows).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (>= 0.6); support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -72,7 +71,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          bq: int = 128, bk: int = 128,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """q: (BH, Sq, dh), k/v: (BH, Sk, dh) — one kv head per BH row
     (GQA group already folded into Sq rows by the ops wrapper)."""
     BH, Sq, dh = q.shape
@@ -107,8 +106,8 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :Sq]

@@ -7,6 +7,7 @@ head) pair becomes one kernel program row — and restores the layout.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 @functools.partial(jax.jit, static_argnames=("causal", "window",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     B, Sq, H, dh = q.shape
     KV = k.shape[2]
     G = H // KV

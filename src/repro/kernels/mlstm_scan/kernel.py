@@ -11,19 +11,24 @@ scratch-across-grid-steps pattern is the TPU-native equivalent
 Time steps within a chunk run as an in-kernel fori_loop: the recurrence
 is inherently sequential; the kernel's win is memory locality, not
 parallelism across time.
+
+The per-step gates are scalars per head. Mosaic cannot broadcast a
+(1, 1) value over both sublanes and lanes of the (dh, dh) memory, so
+each gate is first spread along the lanes into a (1, dh) row (and the
+stabilizer m is kept as such a row): the row then broadcasts over
+sublanes only.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (>= 0.6); support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -42,16 +47,18 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, o_ref,
         q_t = q_ref[0, pl.ds(t, 1)]          # (1, dh)
         k_t = k_ref[0, pl.ds(t, 1)]
         v_t = v_ref[0, pl.ds(t, 1)]
-        ig = ig_ref[0, pl.ds(t, 1)]          # (1, 1)
-        fg = fg_ref[0, pl.ds(t, 1)]
+        row = lambda g: jnp.broadcast_to(g, (1, dh))
+        ig = row(ig_ref[0, pl.ds(t, 1)])     # (1, dh), one value
+        fg = row(fg_ref[0, pl.ds(t, 1)])
         logf = jax.nn.log_sigmoid(fg)
-        m_prev = m_ref[...]                  # (1, 1)
+        m_prev = m_ref[...]                  # (1, dh), one value
         m_new = jnp.maximum(logf + m_prev, ig)
-        i_p = jnp.exp(ig - m_new)            # (1, 1)
+        i_p = jnp.exp(ig - m_new)            # (1, dh)
         f_p = jnp.exp(logf + m_prev - m_new)
-        # C <- f C + i (v^T k): (dh, dh)
-        c_ref[...] = f_p * c_ref[...] + i_p * jax.lax.dot_general(
-            v_t, k_t, (((0,), (0,)), ((), ())),
+        # C <- f C + v^T (i k): (dh, dh); the gates scale columns, and
+        # every column gets the same factor
+        c_ref[...] = f_p * c_ref[...] + jax.lax.dot_general(
+            v_t, i_p * k_t, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         n_ref[...] = f_p * n_ref[...] + i_p * k_t
         m_ref[...] = m_new
@@ -68,7 +75,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, o_ref,
 
 
 def mlstm_scan_bhsd(q, k, v, ig, fg, *, chunk: int = 64,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q/k/v: (BH, S, dh) f32; ig/fg: (BH, S, 1) gate pre-activations.
     Returns h: (BH, S, dh)."""
     BH, S, dh = q.shape
@@ -93,10 +100,10 @@ def mlstm_scan_bhsd(q, k, v, ig, fg, *, chunk: int = 64,
         scratch_shapes=[
             pltpu.VMEM((dh, dh), jnp.float32),
             pltpu.VMEM((1, dh), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, ig, fg)
     return out[:, :S]

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,7 @@ from repro.kernels.mlstm_scan.kernel import mlstm_scan_bhsd
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def mlstm_scan(q, k, v, ig, fg, *, chunk: int = 64, interpret: bool = True):
+def mlstm_scan(q, k, v, ig, fg, *, chunk: int = 64, interpret: Optional[bool] = None):
     """q/k/v: (B, S, H, dh); ig/fg: (B, S, H). Returns (B, S, H, dh)."""
     B, S, H, dh = q.shape
     fold = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, S, a.shape[-1])

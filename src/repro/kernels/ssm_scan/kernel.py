@@ -5,20 +5,23 @@ lives in VMEM scratch across the sequential chunk grid dimension — HBM
 sees only inputs and outputs, never the state. The per-step decay
 exp(dt*A) is precomputed by the ops wrapper (elementwise, XLA does it
 well); the kernel owns the recurrence, which XLA cannot fuse into a
-state-resident loop on its own.
+state-resident loop on its own. The per-step decay is a scalar per
+head, but it arrives spread over the N state columns, as B and C do:
+a (1, N) row scales the (P, N) state by broadcasting over sublanes
+only, while Mosaic cannot broadcast a (1, 1) value over sublanes and
+lanes at once.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (>= 0.6); support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 
 def _ssm_kernel(x_ref, decay_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *,
@@ -31,7 +34,7 @@ def _ssm_kernel(x_ref, decay_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *,
 
     def step(t, _):
         x_t = x_ref[0, pl.ds(t, 1)]          # (1, P)
-        dec = decay_ref[0, pl.ds(t, 1)]      # (1, 1)
+        dec = decay_ref[0, pl.ds(t, 1)]      # (1, N), one value
         dt = dt_ref[0, pl.ds(t, 1)]          # (1, 1)
         b_t = b_ref[0, pl.ds(t, 1)]          # (1, N)
         c_t = c_ref[0, pl.ds(t, 1)]          # (1, N)
@@ -51,8 +54,8 @@ def _ssm_kernel(x_ref, decay_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *,
 
 
 def ssm_scan_bhspn(x, decay, dt, b, c, *, chunk: int = 64,
-                   interpret: bool = True):
-    """x: (BH, S, P); decay/dt: (BH, S, 1); b/c: (BH, S, N).
+                   interpret: Optional[bool] = None):
+    """x: (BH, S, P); dt: (BH, S, 1); decay/b/c: (BH, S, N).
     Returns y: (BH, S, P) (without the D*x skip, added by the caller)."""
     BH, S, P = x.shape
     N = b.shape[-1]
@@ -72,12 +75,12 @@ def ssm_scan_bhspn(x, decay, dt, b, c, *, chunk: int = 64,
     out = pl.pallas_call(
         kernel,
         grid=(BH, nc),
-        in_specs=[spec(P), spec(1), spec(1), spec(N), spec(N)],
+        in_specs=[spec(P), spec(N), spec(1), spec(N), spec(N)],
         out_specs=spec(P),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, decay, dt, b, c)
     return out[:, :S]

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,7 @@ from repro.kernels.ssm_scan.kernel import ssm_scan_bhspn
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssm_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 64,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """x: (B,S,Hs,P); dt: (B,S,Hs); a_log/d_skip: (Hs,); b/c: (B,S,N).
     Returns y: (B,S,Hs,P) including the D*x skip."""
     B, S, Hs, P = x.shape
@@ -20,7 +21,8 @@ def ssm_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 64,
     decay = jnp.exp(dt.astype(jnp.float32) * A)             # (B,S,Hs)
     fold = lambda a: a.transpose(0, 2, 1, 3).reshape(B * Hs, S, -1)
     xf = fold(x.astype(jnp.float32))
-    decf = decay.transpose(0, 2, 1).reshape(B * Hs, S, 1)
+    decf = jnp.broadcast_to(decay.transpose(0, 2, 1)[..., None],
+                            (B, Hs, S, N)).reshape(B * Hs, S, N)
     dtf = dt.astype(jnp.float32).transpose(0, 2, 1).reshape(B * Hs, S, 1)
     bf = jnp.broadcast_to(b[:, None], (B, Hs, S, N)).reshape(B * Hs, S, N)
     cf = jnp.broadcast_to(c[:, None], (B, Hs, S, N)).reshape(B * Hs, S, N)

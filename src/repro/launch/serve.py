@@ -4,7 +4,8 @@ Two modes:
   --mode sim   (default): discrete-event simulation of a device pool with
                the paper's workloads or the assigned model endpoints.
   --mode real  : real JAX execution of reduced-config endpoints on this
-               host (the end-to-end driver used by examples/serve_trace.py).
+               host's default backend, with the persistent compilation
+               cache on (``chip_smoke.py`` serves the published widths).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.serve --policy mqfq-sticky \
@@ -54,17 +55,12 @@ def run_sim_mode(args) -> dict:
 
 
 def run_real_mode(args) -> dict:
-    from repro.configs import get_config
-    from repro.runtime.device import JaxEndpoint
+    from repro.runtime.device import build_endpoints
     from repro.server import ServerConfig, make_server
 
-    import dataclasses
     archs = args.archs.split(",")
-    endpoints = {
-        a: JaxEndpoint(
-            a, dataclasses.replace(get_config(a).reduced(),
-                                   kv_quant=args.kv_quant), seed=i)
-        for i, a in enumerate(archs)}
+    endpoints = build_endpoints({a: (a, i) for i, a in enumerate(archs)},
+                                kv_quant=args.kv_quant)
     kw = dict(T=args.T, alpha=args.alpha) \
         if args.policy in ("mqfq", "mqfq-sticky") else {}
     # cap residency at roughly half the endpoints (the old engine's

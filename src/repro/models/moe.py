@@ -201,10 +201,10 @@ def moe_apply_ep(cfg: ModelConfig, p, x):
             y = jax.lax.pmean(y, dp)
         return y, aux
 
-    from repro.utils.shardctx import shard_map_compat
-    fn = shard_map_compat(
-        local, mesh,
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),
+        check_vma=False,
     )
     return fn(x, p["router"], p["we1"], p["we3"], p["we2"])

@@ -53,6 +53,7 @@ def xlstm_param_table(cfg: ModelConfig) -> Dict:
             "s_norm": ParamDef((P, d), (None, None), init="ones"),
             "s_w": col(P, d, 4 * d),
             "s_r": mk(P, d, 4 * d),
+            "s_ffn_norm": ParamDef((P, d), (None, None), init="ones"),
             "s_up1": col(P, d, dsf),
             "s_up2": col(P, d, dsf),
             "s_down": ParamDef((P, dsf, d), (None, "model", None)),
@@ -154,9 +155,12 @@ def slstm_apply(cfg: ModelConfig, p, x, state):
     state = dict(zip(("c", "n", "m", "h"), carry))
     h = hs.transpose(1, 0, 2).astype(x.dtype)        # (B,S,d)
     x = x + h
-    # gated ffn (proj factor 4/3)
-    y = jax.nn.gelu((x @ p["s_up1"]).astype(jnp.float32)).astype(x.dtype) \
-        * (x @ p["s_up2"])
+    # gated ffn (proj factor 4/3) on the normed stream: fed the raw
+    # residual, the product of two projections squares its scale at
+    # every block and a 24-layer stack overflows to inf
+    xf = rms_norm(x, p["s_ffn_norm"])
+    y = jax.nn.gelu((xf @ p["s_up1"]).astype(jnp.float32)).astype(x.dtype) \
+        * (xf @ p["s_up2"])
     return x + y @ p["s_down"], state
 
 

@@ -1,32 +1,52 @@
 """Real-execution endpoints: the JAX device path.
 
-A ``JaxEndpoint`` is one serveable function: a model (reduced config on
-CPU; full config on a real slice), host-resident weights (numpy), and
-jitted prefill/decode executables. The memory manager's abstract
-"regions" map to real bytes here:
+A ``JaxEndpoint`` is one serveable function: a model (a reduced config
+on the CPU test rig, published widths on a TPU), host-resident weights
+(numpy), and jitted prefill/decode executables. The memory manager's
+abstract "regions" map to real bytes here:
 
   cold       — build + compile + upload   (first instantiation)
   host_warm  — weights evicted from device: re-upload only
   warm       — device-resident: execute immediately
 
-On the CPU test rig "host" is numpy and "device" is jax.Array — upload
-(``jax.device_put``) and eviction are real operations with real cost,
-so the control-plane integration is exercised end to end.
+Residency is per device: ``upload(dev_id)`` puts one copy of the weights
+on ``jax.devices()[dev_id]``, the chip the control plane placed the
+invocation on, and ``evict(dev_id)`` drops only that copy. Uploads and
+evictions are real operations with real cost on every backend, so the
+control-plane integration is exercised end to end.
 """
 from __future__ import annotations
 
+import dataclasses
+import os
 import threading
 import time
-from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from pathlib import Path
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.models import build_model, decode_cache_plan
 from repro.shapes import InputShape
+
+# fixed, so that one checkout's runs find each other's compiles
+# (the cache is keyed by path); listed in .gitignore
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path:
+    ``$JAX_COMPILATION_CACHE_DIR`` where that is set, else ``CACHE_DIR``.
+    Every compile is kept however short it was, since a cold start pays
+    for each one it misses."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 class JaxEndpoint:
@@ -39,33 +59,39 @@ class JaxEndpoint:
         self.serve_shape = InputShape("serve", serve_seq, serve_batch,
                                       "prefill")
         self.decode_steps = decode_steps
-        self.plan = decode_cache_plan(cfg, serve_seq)
+        # room for the prompt and every decoded token: a full cache of
+        # serve_seq slots would clamp the first decode write onto the
+        # prompt's last slot
+        self.plan = decode_cache_plan(cfg, serve_seq + decode_steps)
         rng = jax.random.PRNGKey(seed)
-        # host weights: numpy (host RAM)
-        params = self.model.init_params(rng)
+        # host weights: numpy (host RAM). One jitted program draws them
+        # all: run eagerly, every leaf shape compiles its own sampler
+        params = jax.jit(self.model.init_params)(rng)
         self.host_params = jax.tree.map(np.asarray, params)
         self.weight_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
-        self.device_params = None
+        self.device_params: Dict[int, Any] = {}   # dev_id -> weights
         self._compiled: Dict[str, Any] = {}
         self.lock = threading.Lock()  # one instance: serialize executions
         self.last_use = 0.0
 
     # -- residency ---------------------------------------------------------
-    @property
-    def resident(self) -> bool:
-        return self.device_params is not None
+    def resident_on(self, dev_id: int = 0) -> bool:
+        return dev_id in self.device_params
 
-    def upload(self) -> float:
+    def upload(self, dev_id: int = 0) -> float:
         t0 = time.monotonic()
-        self.device_params = jax.tree.map(jnp.asarray, self.host_params)
-        jax.block_until_ready(self.device_params)
+        params = jax.device_put(self.host_params, jax.devices()[dev_id])
+        jax.block_until_ready(params)
+        self.device_params[dev_id] = params
         return time.monotonic() - t0
 
-    def evict(self) -> None:
-        self.device_params = None
+    def evict(self, dev_id: int = 0) -> None:
+        self.device_params.pop(dev_id, None)
 
     # -- compilation (the "container init" analogue) -------------------------
-    def compile(self) -> float:
+    def compile(self, dev_id: int = 0) -> float:
+        """Trace and compile prefill and decode (with the persistent
+        cache on, a repeat finds both there), uploading first if needed."""
         t0 = time.monotonic()
         plan = self.plan
         model = self.model
@@ -80,15 +106,16 @@ class JaxEndpoint:
             return model.decode_fn(params, cache, tok, pos, ring=plan.ring)
 
         compiled = {"prefill": jax.jit(_prefill), "decode": jax.jit(_decode)}
+        if not self.resident_on(dev_id):
+            self.upload(dev_id)
+        params = self.device_params[dev_id]
         # trigger compilation with abstract-matching dummy batch
-        batch = self.model.make_batch(self.serve_shape)
-        if self.device_params is None:
-            self.upload()
-        logits, cache = compiled["prefill"](self.device_params, batch)
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        pos = batch["tokens"].shape[1] + (
-            self.cfg.n_patches if self.cfg.family == "vlm" else 0)
-        compiled["decode"](self.device_params, cache, tok, pos)
+        with jax.default_device(jax.devices()[dev_id]):
+            batch = self.model.make_batch(self.serve_shape)
+            logits, cache = compiled["prefill"](params, batch)
+            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            logits, _ = compiled["decode"](params, cache, tok,
+                                           self.prompt_len(batch))
         jax.block_until_ready(logits)
         self._compiled = compiled  # publish atomically: compiled only when usable
         return time.monotonic() - t0
@@ -98,23 +125,60 @@ class JaxEndpoint:
         return bool(self._compiled)
 
     # -- serving -----------------------------------------------------------
-    def execute(self, request: Optional[dict] = None) -> Dict[str, float]:
-        """One batched request: prefill + a few decode steps."""
-        assert self.resident and self.compiled
-        t0 = time.monotonic()
-        batch = self.model.make_batch(
-            self.serve_shape,
-            rng=jax.random.PRNGKey((request or {}).get("seed", 0)))
-        logits, cache = self._compiled["prefill"](self.device_params, batch)
-        pos = batch["tokens"].shape[1] + (
+    def prompt_len(self, batch: dict) -> int:
+        """Position of the first decoded token (VLM patches come first)."""
+        return batch["tokens"].shape[1] + (
             self.cfg.n_patches if self.cfg.family == "vlm" else 0)
+
+    def prefill(self, batch: dict, dev_id: int = 0):
+        """Compiled prefill on device ``dev_id``: (last logits, cache)."""
+        return self._compiled["prefill"](self.device_params[dev_id], batch)
+
+    def decode(self, cache, tok, pos, dev_id: int = 0):
+        """One compiled decode step on device ``dev_id``."""
+        return self._compiled["decode"](self.device_params[dev_id], cache,
+                                        tok, pos)
+
+    def execute(self, request: Optional[dict] = None,
+                dev_id: int = 0) -> Dict[str, Any]:
+        """One batched request on device ``dev_id``: prefill + a few
+        greedy decode steps. ``device`` is where the logits were made,
+        ``weight_devices`` where the weights it read live."""
+        assert self.resident_on(dev_id) and self.compiled
+        t0 = time.monotonic()
+        with jax.default_device(jax.devices()[dev_id]):
+            batch = self.model.make_batch(
+                self.serve_shape,
+                rng=jax.random.PRNGKey((request or {}).get("seed", 0)))
+        logits, cache = self.prefill(batch, dev_id)
+        pos = self.prompt_len(batch)
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
         toks = []
         for i in range(self.decode_steps):
-            logits, cache = self._compiled["decode"](
-                self.device_params, cache, tok, pos + i)
+            logits, cache = self.decode(cache, tok, pos + i, dev_id)
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
             toks.append(np.asarray(tok))
         jax.block_until_ready(logits)
         return {"exec_s": time.monotonic() - t0,
-                "tokens": np.concatenate(toks, axis=1)}
+                "tokens": np.concatenate(toks, axis=1),
+                "device": next(iter(logits.devices())),
+                "weight_devices": {d for leaf in jax.tree.leaves(
+                    self.device_params[dev_id]) for d in leaf.devices()}}
+
+
+def build_endpoints(fns: Mapping[str, Tuple[str, int]], *,
+                    full_width: bool = False, kv_quant: bool = False,
+                    **endpoint_kw) -> Dict[str, JaxEndpoint]:
+    """One ``JaxEndpoint`` per ``fn_id -> (arch, seed)``: the published
+    config with ``full_width`` (bf16 weights), else its reduced smoke
+    variant. Turns on the persistent compilation cache first."""
+    configure_compile_cache()
+    out = {}
+    for fn_id, (arch, seed) in fns.items():
+        cfg = get_config(arch)
+        if not full_width:
+            cfg = cfg.reduced()
+        if kv_quant:
+            cfg = dataclasses.replace(cfg, kv_quant=True)
+        out[fn_id] = JaxEndpoint(fn_id, cfg, seed=seed, **endpoint_kw)
+    return out

@@ -6,7 +6,8 @@ dominated RSS. Slots cut ~45% per record and make attribute access on
 the event-loop hot path cheaper. Everything the lifecycle ever sets is a
 declared field — including ``charged_tau`` (the VT charge pinned at
 dispatch for the deficit settle) and ``request`` (the wall-clock
-executor's payload), which used to be monkey-patched on.
+executor's payload), which used to be monkey-patched on, and ``output``
+(what the endpoint's ``execute`` returned).
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ class Invocation:
     device_id: int = 0
     charged_tau: Optional[float] = None  # tau charged to VT at dispatch
     request: Optional[dict] = None       # wall-clock request payload
+    output: Optional[dict] = None        # wall-clock endpoint result
     # fault plane (ISSUE 9): attempt retries consumed, and the final
     # disposition flags — ``shed`` (rejected at arrival by degraded-mode
     # load shedding, never queued) and ``failed`` (an injected fault the

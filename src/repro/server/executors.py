@@ -23,6 +23,7 @@ config selects.
 """
 from __future__ import annotations
 
+import functools
 import heapq
 import itertools
 import threading
@@ -613,11 +614,16 @@ class WallClockExecutor:
         self._pending_retries = 0
         self._doomed: set = set()          # inv_ids doomed by device fault
         self._watchdog: Optional[threading.Thread] = None
+        # first exception an endpoint raised outside the fault plane;
+        # drain()/stop() re-raise it (once) so a broken run cannot exit
+        # clean
+        self._error: Optional[BaseException] = None
         # control-plane events -> real data movement
         if subscribe_state:
             control.bus.on_state_change(self._on_state_change)
         for dev in control.devices:
-            dev.mem.evict_listeners.append(self._on_region_evicted)
+            dev.mem.evict_listeners.append(
+                functools.partial(self._on_region_evicted, dev.dev_id))
 
     # -- time ---------------------------------------------------------------
     def now(self) -> float:
@@ -626,32 +632,48 @@ class WallClockExecutor:
     # -- memory integration ----------------------------------------------------
     def _on_state_change(self, ev) -> None:
         """Anticipatory prefetch: queue turned Active -> upload weights
-        asynchronously, off the critical path (§4.3)."""
+        asynchronously to the function's sticky device, off the critical
+        path (§4.3)."""
         ep = self.endpoints.get(ev.fn_id)
         if ep is None or ev.new is not QueueState.ACTIVE:
             return
+        dev_id = self.control._fn_device(ev.fn_id).dev_id
         try:
-            self._pool.submit(self._prefetch, ep)
+            self._pool.submit(self._prefetch, ep, dev_id)
         except RuntimeError:
             pass  # pool shutting down: prefetch is best-effort anyway
 
-    @staticmethod
-    def _prefetch(ep) -> None:
-        with ep.lock:
-            if ep.compiled and not ep.resident:
-                ep.upload()
+    def _prefetch(self, ep, dev_id: int) -> None:
+        try:
+            with ep.lock:
+                if ep.compiled and not ep.resident_on(dev_id):
+                    ep.upload(dev_id)
+        except Exception as e:  # noqa: BLE001 - surfaced by drain/stop
+            self._record_error(e)
 
-    def _on_region_evicted(self, fn_id: str) -> None:
-        """The memory manager swapped a region out: mirror it on the real
-        endpoint (skip if the function is mid-execution; accounting and
-        reality reconcile at its next dispatch)."""
+    def _record_error(self, e: BaseException) -> None:
+        with self._lock:
+            if self._error is None:
+                self._error = e
+            self._idle.notify_all()
+
+    def _raise_error(self) -> None:
+        e, self._error = self._error, None
+        if e is not None:
+            raise e
+
+    def _on_region_evicted(self, dev_id: int, fn_id: str) -> None:
+        """The memory manager of device ``dev_id`` swapped a region out:
+        drop that device's copy of the weights (skip if the function is
+        mid-execution; accounting and reality reconcile at its next
+        dispatch)."""
         ep = self.endpoints.get(fn_id)
         if ep is None:
             return
         q = self.control.policy.queues.get(fn_id)
         if q is not None and q.in_flight > 0:
             return
-        ep.evict()
+        ep.evict(dev_id)
 
     # -- API ------------------------------------------------------------------
     def submit(self, fn_id: str, request: Optional[dict] = None
@@ -701,7 +723,9 @@ class WallClockExecutor:
             if self._watchdog is not None:
                 self._watchdog.join(timeout=5)
             self._pool.shutdown(wait=False, cancel_futures=True)
+            self._raise_error()
             raise TimeoutError("engine did not drain")
+        self._raise_error()
 
     def stop(self) -> RunResult:
         self._stop.set()
@@ -711,6 +735,7 @@ class WallClockExecutor:
         if self._watchdog is not None:
             self._watchdog.join(timeout=10)
         self._pool.shutdown(wait=True)
+        self._raise_error()
         cp = self.control
         inj = self._injector
         return RunResult(cp.policy.name, list(self.completed), cp.fairness,
@@ -811,6 +836,7 @@ class WallClockExecutor:
     def _execute(self, d: DispatchDecision) -> None:
         inv = d.inv
         ep = self.endpoints[inv.fn_id]
+        dev_id = d.device.dev_id
         inj = self._injector
         fault: Optional[str] = None
         try:
@@ -829,18 +855,21 @@ class WallClockExecutor:
                     # cold -> compile (+upload), host_warm/warm -> ensure
                     # weights are on device (prefetch may still be in flight)
                     if not ep.compiled:
-                        ep.compile()
-                    elif not ep.resident:
-                        ep.upload()
+                        ep.compile(dev_id)
+                    elif not ep.resident_on(dev_id):
+                        ep.upload(dev_id)
                     ep.last_use = self.now()
                     inv.exec_start = self.now()
                     inv.overhead = inv.exec_start - overhead0
-                    out = ep.execute(getattr(inv, "request", None))
+                    out = ep.execute(inv.request, dev_id)
                     inv.service_time = out["exec_s"]
+                    inv.output = out
             except FaultError as e:
                 fault = e.mode
-                if inv.service_time is None:
-                    inv.service_time = 0.0
+            except Exception as e:  # noqa: BLE001 - surfaced by drain/stop
+                # not an injected fault: no retry, the run must fail
+                inv.failed = True
+                self._record_error(e)
         finally:
             if inj is not None:
                 with self._lock:
@@ -953,7 +982,14 @@ class ShardedWallClockExecutor:
         self._stop_evt.set()
         if self._vt_thread is not None:
             self._vt_thread.join(timeout=10)
-        results = [ex.stop() for ex in self.execs]
+        results, errors = [], []
+        for ex in self.execs:       # stop every shard before raising
+            try:
+                results.append(ex.stop())
+            except Exception as e:  # noqa: BLE001 - re-raised below
+                errors.append(e)
+        if errors:
+            raise errors[0]
         sh = self.sharded
         invocations = [i for r in results for i in r.invocations]
         invocations.sort(key=lambda i: (

@@ -40,7 +40,7 @@ class StubEndpoint:
         self.lock = threading.Lock()
         self.last_use = 0.0
         self._compiled = False
-        self._resident = False
+        self._resident: set = set()        # dev_ids holding the weights
         # op counters (asserted by tests)
         self.compile_count = 0
         self.upload_count = 0
@@ -51,31 +51,31 @@ class StubEndpoint:
     def compiled(self) -> bool:
         return self._compiled
 
-    @property
-    def resident(self) -> bool:
-        return self._resident
+    def resident_on(self, dev_id: int = 0) -> bool:
+        return dev_id in self._resident
 
-    def compile(self) -> float:
+    def compile(self, dev_id: int = 0) -> float:
         if self.cold_delay:
             time.sleep(self.cold_delay)
         self._compiled = True
-        self._resident = True
+        self._resident.add(dev_id)
         self.compile_count += 1
         return self.cold_delay
 
-    def upload(self) -> float:
+    def upload(self, dev_id: int = 0) -> float:
         if self.upload_delay:
             time.sleep(self.upload_delay)
-        self._resident = True
+        self._resident.add(dev_id)
         self.upload_count += 1
         return self.upload_delay
 
-    def evict(self) -> None:
-        self._resident = False
+    def evict(self, dev_id: int = 0) -> None:
+        self._resident.discard(dev_id)
         self.evict_count += 1
 
-    def execute(self, request: Optional[dict] = None) -> Dict[str, float]:
-        assert self._compiled and self._resident
+    def execute(self, request: Optional[dict] = None,
+                dev_id: int = 0) -> Dict[str, float]:
+        assert self._compiled and dev_id in self._resident
         self.execute_count += 1
         if self.delay:
             time.sleep(self.delay)

@@ -59,20 +59,6 @@ def maybe_shard(x, *entries):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """Version-portable shard_map: ``jax.shard_map`` (jax >= 0.6, kwarg
-    ``check_vma``) when present, else ``jax.experimental.shard_map``
-    (kwarg ``check_rep``)."""
-    try:
-        from jax import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
-
-
 def batch_axis():
     """Logical batch axes for the current mesh ('pod','data') or ('data',)."""
     mesh = current_mesh()

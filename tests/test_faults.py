@@ -119,7 +119,7 @@ def test_faulty_endpoint_injects_on_the_scheduled_attempt():
     ep = FaultyEndpoint(StubEndpoint("f", FunctionSpec("f", 0.0, 0.0, 1)),
                         inj)
     ep.compile()                        # protocol delegation
-    assert ep.compiled and ep.resident and ep.weight_bytes == 1
+    assert ep.compiled and ep.resident_on(0) and ep.weight_bytes == 1
     ep.execute()                        # attempt 0: clean
     with pytest.raises(FaultError) as e:
         ep.execute()                    # attempt 1: scheduled error
