@@ -153,14 +153,6 @@ class FaultyEndpoint:
     def weight_bytes(self) -> int:
         return self._inner.weight_bytes
 
-    @property
-    def last_use(self):
-        return self._inner.last_use
-
-    @last_use.setter
-    def last_use(self, v) -> None:
-        self._inner.last_use = v
-
     def compile(self, dev_id: int = 0) -> None:
         self._inner.compile(dev_id)
 

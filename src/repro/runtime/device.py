@@ -72,7 +72,6 @@ class JaxEndpoint:
         self.device_params: Dict[int, Any] = {}   # dev_id -> weights
         self._compiled: Dict[str, Any] = {}
         self.lock = threading.Lock()  # one instance: serialize executions
-        self.last_use = 0.0
 
     # -- residency ---------------------------------------------------------
     def resident_on(self, dev_id: int = 0) -> bool:
@@ -143,9 +142,12 @@ class JaxEndpoint:
                 dev_id: int = 0) -> Dict[str, Any]:
         """One batched request on device ``dev_id``: prefill + a few
         greedy decode steps. ``device`` is where the logits were made,
-        ``weight_devices`` where the weights it read live."""
+        ``weight_devices`` where the weights it read live;
+        ``device_wait_s`` is the time spent blocked on device results
+        (each decoded token's read and the final wait)."""
         assert self.resident_on(dev_id) and self.compiled
         t0 = time.monotonic()
+        waited = 0.0
         with jax.default_device(jax.devices()[dev_id]):
             batch = self.model.make_batch(
                 self.serve_shape,
@@ -157,9 +159,14 @@ class JaxEndpoint:
         for i in range(self.decode_steps):
             logits, cache = self.decode(cache, tok, pos + i, dev_id)
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            t = time.monotonic()
             toks.append(np.asarray(tok))
+            waited += time.monotonic() - t
+        t = time.monotonic()
         jax.block_until_ready(logits)
+        waited += time.monotonic() - t
         return {"exec_s": time.monotonic() - t0,
+                "device_wait_s": waited,
                 "tokens": np.concatenate(toks, axis=1),
                 "device": next(iter(logits.devices())),
                 "weight_devices": {d for leaf in jax.tree.leaves(

@@ -25,7 +25,7 @@ class Invocation:
     exec_start: Optional[float] = None   # after cold-start / upload overhead
     completion: Optional[float] = None
     start_type: str = ""                 # warm | host_warm | cold
-    overhead: float = 0.0                # cold start + memory wait
+    overhead: float = 0.0                # lock wait + compile + upload
     service_time: float = 0.0            # device execution time
     device_id: int = 0
     charged_tau: Optional[float] = None  # tau charged to VT at dispatch

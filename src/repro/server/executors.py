@@ -31,6 +31,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
+from repro import obs
 from repro.core.flow import QueueState
 from repro.faults import FaultError
 from repro.runtime.invocation import Invocation
@@ -618,6 +619,8 @@ class WallClockExecutor:
         # drain()/stop() re-raise it (once) so a broken run cannot exit
         # clean
         self._error: Optional[BaseException] = None
+        # fn_id -> executor time its queue turned THROTTLED (under _lock)
+        self._throttled_at: Dict[str, float] = {}
         # control-plane events -> real data movement
         if subscribe_state:
             control.bus.on_state_change(self._on_state_change)
@@ -633,7 +636,15 @@ class WallClockExecutor:
     def _on_state_change(self, ev) -> None:
         """Anticipatory prefetch: queue turned Active -> upload weights
         asynchronously to the function's sticky device, off the critical
-        path (§4.3)."""
+        path (§4.3). A queue's stay in THROTTLED is kept as an
+        ``mqfq.throttled`` span."""
+        if ev.new is QueueState.THROTTLED:
+            self._throttled_at[ev.fn_id] = ev.time
+        elif ev.old is QueueState.THROTTLED:
+            since = self._throttled_at.pop(ev.fn_id, None)
+            if since is not None:
+                obs.record("mqfq.throttled", None, ev.fn_id,
+                           since + self._t0, ev.time + self._t0)
         ep = self.endpoints.get(ev.fn_id)
         if ep is None or ev.new is not QueueState.ACTIVE:
             return
@@ -816,6 +827,9 @@ class WallClockExecutor:
         """Hand one decision to the worker pool (hoisted out of
         ``_dispatch_batch`` so the dispatcher loop does not allocate a
         closure per pass). Callers hold ``_lock``."""
+        inv = decision.inv
+        obs.record("inv.queue", inv.inv_id, inv.fn_id, inv.arrival + self._t0,
+                   inv.dispatch_time + self._t0)
         self._inflight += 1
         self._pool.submit(self._execute, decision)
 
@@ -834,11 +848,21 @@ class WallClockExecutor:
             return True
 
     def _execute(self, d: DispatchDecision) -> None:
+        """One dispatched invocation on a worker thread. Its spans run on
+        from ``inv.queue`` without a gap: ``inv.handoff`` (dispatch to
+        this thread's start), ``inv.lock_wait``, ``inv.compile`` or
+        ``inv.upload``, ``inv.execute`` and ``inv.complete`` (to the
+        completion stamped under ``_lock``)."""
         inv = d.inv
-        ep = self.endpoints[inv.fn_id]
+        iid, fn = inv.inv_id, inv.fn_id
+        t0 = self._t0
+        begun = time.monotonic()
+        obs.record("inv.handoff", iid, fn, inv.dispatch_time + t0, begun)
+        ep = self.endpoints[fn]
         dev_id = d.device.dev_id
         inj = self._injector
         fault: Optional[str] = None
+        done: Optional[float] = None     # end of execute, monotonic
         try:
             try:
                 if inj is not None and not self._recovery \
@@ -848,22 +872,39 @@ class WallClockExecutor:
                     inv.exec_start = self.now()
                     inv.overhead = 0.0
                     inv.service_time = 0.0
-                    raise FaultError(inv.fn_id, "device")
-                overhead0 = self.now()
-                with ep.lock:  # one container instance: run-to-completion
+                    raise FaultError(fn, "device")
+                # one container instance: run-to-completion
+                held = False
+                try:
+                    with obs.span("inv.lock_wait", iid, fn,
+                                  start=begun) as sp:
+                        held = ep.lock.acquire()
                     # reconcile reality with the control plane's decision:
                     # cold -> compile (+upload), host_warm/warm -> ensure
                     # weights are on device (prefetch may still be in flight)
                     if not ep.compiled:
-                        ep.compile(dev_id)
+                        with obs.span("inv.compile", iid, fn,
+                                      start=sp.end) as sp:
+                            ep.compile(dev_id)
                     elif not ep.resident_on(dev_id):
-                        ep.upload(dev_id)
-                    ep.last_use = self.now()
-                    inv.exec_start = self.now()
-                    inv.overhead = inv.exec_start - overhead0
-                    out = ep.execute(inv.request, dev_id)
+                        with obs.span("inv.upload", iid, fn, start=sp.end,
+                                      bytes=ep.weight_bytes) as sp:
+                            ep.upload(dev_id)
+                    # overhead: lock wait, compile and upload
+                    inv.exec_start = sp.end - t0
+                    inv.overhead = sp.end - begun
+                    with obs.span("inv.execute", iid, fn,
+                                  start=sp.end) as sp:
+                        out = ep.execute(inv.request, dev_id)
+                        # stubs wait on no device: they report none
+                        sp.attrs["device_wait_s"] = out.get(
+                            "device_wait_s", 0.0)
+                    done = sp.end
                     inv.service_time = out["exec_s"]
                     inv.output = out
+                finally:
+                    if held:
+                        ep.lock.release()
             except FaultError as e:
                 fault = e.mode
             except Exception as e:  # noqa: BLE001 - surfaced by drain/stop
@@ -885,6 +926,8 @@ class WallClockExecutor:
                 with self._lock:
                     now = self.now()
                     inv.completion = now
+                    if done is not None:
+                        obs.record("inv.complete", iid, fn, done, now + t0)
                     self.completed.append(inv)
                     self.control.on_complete(inv, now)
                     self.control.sample(now)

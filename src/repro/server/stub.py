@@ -38,7 +38,6 @@ class StubEndpoint:
         self.upload_delay = upload_delay
         self.weight_bytes = spec.mem_bytes
         self.lock = threading.Lock()
-        self.last_use = 0.0
         self._compiled = False
         self._resident: set = set()        # dev_ids holding the weights
         # op counters (asserted by tests)
