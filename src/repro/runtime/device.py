@@ -30,12 +30,17 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
-from repro.models import build_model, decode_cache_plan
+from repro.models import CachePlan, Model, build_model, decode_cache_plan
 from repro.shapes import InputShape
 
 # fixed, so that one checkout's runs find each other's compiles
 # (the cache is keyed by path); listed in .gitignore
 CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def _greedy(logits):
+    """The greedy token of each row, as a ``(batch, 1)`` int32 column."""
+    return jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
 
 
 def configure_compile_cache() -> str:
@@ -47,6 +52,42 @@ def configure_compile_cache() -> str:
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
+
+
+def served_programs(model: Model, plan: CachePlan,
+                    decode_steps: int) -> Dict[str, Any]:
+    """The jitted programs an endpoint serves with: ``prefill`` (batch
+    to last logits and cache), ``decode`` (one step) and ``decode_loop``
+    (prefill's logits and cache to ``decode_steps`` greedy tokens)."""
+
+    def _prefill(params, batch):
+        if plan.kind == "state":
+            return model.prefill_fn(params, batch)
+        return model.prefill_fn(params, batch, cache_len=plan.length,
+                                ring=plan.ring)
+
+    def _decode(params, cache, tok, pos):
+        return model.decode_fn(params, cache, tok, pos, ring=plan.ring)
+
+    def _decode_loop(params, cache, logits, pos):
+        """Greedy decoding on the device: the prefill's argmax, then
+        ``decode_steps`` decode steps, each fed the previous argmax.
+        Returns the ``(batch, decode_steps)`` tokens and the last
+        logits."""
+        def step(i, carry):
+            cache, tok, toks, _ = carry
+            logits, cache = _decode(params, cache, tok, pos + i)
+            tok = _greedy(logits)
+            toks = jax.lax.dynamic_update_slice(toks, tok, (0, i))
+            return cache, tok, toks, logits
+
+        toks = jnp.zeros((logits.shape[0], decode_steps), jnp.int32)
+        carry = (cache, _greedy(logits), toks, logits)
+        _, _, toks, logits = jax.lax.fori_loop(0, decode_steps, step, carry)
+        return toks, logits
+
+    return {"prefill": jax.jit(_prefill), "decode": jax.jit(_decode),
+            "decode_loop": jax.jit(_decode_loop)}
 
 
 class JaxEndpoint:
@@ -89,22 +130,12 @@ class JaxEndpoint:
 
     # -- compilation (the "container init" analogue) -------------------------
     def compile(self, dev_id: int = 0) -> float:
-        """Trace and compile prefill and decode (with the persistent
-        cache on, a repeat finds both there), uploading first if needed."""
+        """Trace and compile the two served programs, prefill and the
+        decode loop (with the persistent cache on, a repeat finds both
+        there), uploading first if needed. The single decode step
+        behind ``decode`` compiles on its first call."""
         t0 = time.monotonic()
-        plan = self.plan
-        model = self.model
-
-        def _prefill(params, batch):
-            if plan.kind == "state":
-                return model.prefill_fn(params, batch)
-            return model.prefill_fn(params, batch, cache_len=plan.length,
-                                    ring=plan.ring)
-
-        def _decode(params, cache, tok, pos):
-            return model.decode_fn(params, cache, tok, pos, ring=plan.ring)
-
-        compiled = {"prefill": jax.jit(_prefill), "decode": jax.jit(_decode)}
+        compiled = served_programs(self.model, self.plan, self.decode_steps)
         if not self.resident_on(dev_id):
             self.upload(dev_id)
         params = self.device_params[dev_id]
@@ -112,10 +143,9 @@ class JaxEndpoint:
         with jax.default_device(jax.devices()[dev_id]):
             batch = self.model.make_batch(self.serve_shape)
             logits, cache = compiled["prefill"](params, batch)
-            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            logits, _ = compiled["decode"](params, cache, tok,
-                                           self.prompt_len(batch))
-        jax.block_until_ready(logits)
+            toks, _ = compiled["decode_loop"](params, cache, logits,
+                                              self.prompt_len(batch))
+        jax.block_until_ready(toks)
         self._compiled = compiled  # publish atomically: compiled only when usable
         return time.monotonic() - t0
 
@@ -140,37 +170,32 @@ class JaxEndpoint:
 
     def execute(self, request: Optional[dict] = None,
                 dev_id: int = 0) -> Dict[str, Any]:
-        """One batched request on device ``dev_id``: prefill + a few
-        greedy decode steps. ``device`` is where the logits were made,
+        """One batched request on device ``dev_id``: prefill, then the
+        greedy decode loop as one device program, whose tokens are the
+        one read back. ``device`` is where the logits were made,
         ``weight_devices`` where the weights it read live;
-        ``device_wait_s`` is the time spent blocked on device results
-        (each decoded token's read and the final wait)."""
+        ``device_wait_s`` is the time spent blocked on that read and
+        ``host_syncs`` the number of blocking reads."""
         assert self.resident_on(dev_id) and self.compiled
         t0 = time.monotonic()
-        waited = 0.0
         with jax.default_device(jax.devices()[dev_id]):
             batch = self.model.make_batch(
                 self.serve_shape,
                 rng=jax.random.PRNGKey((request or {}).get("seed", 0)))
+        params = self.device_params[dev_id]
         logits, cache = self.prefill(batch, dev_id)
-        pos = self.prompt_len(batch)
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        toks = []
-        for i in range(self.decode_steps):
-            logits, cache = self.decode(cache, tok, pos + i, dev_id)
-            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            t = time.monotonic()
-            toks.append(np.asarray(tok))
-            waited += time.monotonic() - t
+        toks, logits = self._compiled["decode_loop"](
+            params, cache, logits, self.prompt_len(batch))
         t = time.monotonic()
-        jax.block_until_ready(logits)
-        waited += time.monotonic() - t
+        tokens = np.array(toks)          # a writable copy, as the caller owns it
+        waited = time.monotonic() - t
         return {"exec_s": time.monotonic() - t0,
                 "device_wait_s": waited,
-                "tokens": np.concatenate(toks, axis=1),
+                "host_syncs": 1,
+                "tokens": tokens,
                 "device": next(iter(logits.devices())),
-                "weight_devices": {d for leaf in jax.tree.leaves(
-                    self.device_params[dev_id]) for d in leaf.devices()}}
+                "weight_devices": {d for leaf in jax.tree.leaves(params)
+                                   for d in leaf.devices()}}
 
 
 def build_endpoints(fns: Mapping[str, Tuple[str, int]], *,

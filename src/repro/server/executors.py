@@ -899,6 +899,8 @@ class WallClockExecutor:
                         # stubs wait on no device: they report none
                         sp.attrs["device_wait_s"] = out.get(
                             "device_wait_s", 0.0)
+                        if "host_syncs" in out:
+                            sp.attrs["host_syncs"] = out["host_syncs"]
                     done = sp.end
                     inv.service_time = out["exec_s"]
                     inv.output = out

@@ -118,3 +118,31 @@ def test_qwen3_decode_step_fits_one_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 3.5e9 < mem.argument_size_in_bytes < total < HBM_BYTES
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "xlstm-350m"])
+def test_served_decode_loop_fits_one_v5e(one_chip, arch):
+    """The served decode loop at published widths (prompt 128, 16
+    greedy tokens, batch 2) compiles for one chip, as one module with
+    the steps in a device loop, and fits it."""
+    from repro.runtime.device import served_programs
+    from repro.shapes import InputShape
+    cfg = get_config(arch)
+    model = build_model(cfg)
+    plan = decode_cache_plan(cfg, 128 + 16)
+    programs = served_programs(model, plan, 16)
+    on_chip = lambda t: jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), t)
+    params = on_chip(jax.eval_shape(model.init_params,
+                                    jax.random.PRNGKey(0)))
+    batch = on_chip(model.make_batch(InputShape("serve", 128, 2, "prefill"),
+                                     abstract=True))
+    logits, cache = on_chip(jax.eval_shape(programs["prefill"], params,
+                                           batch))
+    compiled = programs["decode_loop"].lower(
+        params, cache, logits, _sds(one_chip, (), jnp.int32)).compile()
+    assert compiled.as_text().startswith("HloModule jit__decode_loop")
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes < total < HBM_BYTES

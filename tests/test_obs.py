@@ -229,7 +229,9 @@ def reduced_endpoint():
 def test_the_served_programs_keep_the_module_names_the_trace_reads(
         reduced_endpoint):
     """The device trace's readers find prefill and decode by the XLA
-    module names ``jit__prefill`` and ``jit__decode``."""
+    module names ``jit__prefill`` and ``jit__decode``; decode work is
+    counted by modules whose name holds ``decode``, as the served decode
+    loop's does."""
     import jax.numpy as jnp
     ep = reduced_endpoint
     params = ep.device_params[0]
@@ -241,6 +243,38 @@ def test_the_served_programs_keep_the_module_names_the_trace_reads(
     decode = ep._compiled["decode"].lower(params, cache, tok,
                                           ep.prompt_len(batch))
     assert "module @jit__decode " in decode.as_text()
+    loop = ep._compiled["decode_loop"].lower(params, cache, logits,
+                                             ep.prompt_len(batch))
+    (name,) = re.findall(r"^module @(\S+) ", loop.as_text(), re.M)
+    assert "decode" in name and "prefill" not in name
+
+
+def test_execute_dispatches_prefill_then_the_decode_loop_alone(
+        reduced_endpoint, monkeypatch):
+    """One execute runs two programs, prefill and the decode loop; no
+    argmax is dispatched on its own between or after them."""
+    import jax.numpy as jnp
+    ep = reduced_endpoint
+    calls, eager = [], []
+
+    def spy(key, program):
+        def call(*args):
+            calls.append(key)
+            return program(*args)
+        return call
+
+    for key, program in dict(ep._compiled).items():
+        monkeypatch.setitem(ep._compiled, key, spy(key, program))
+    argmax = jnp.argmax
+
+    def eager_argmax(*args, **kw):
+        eager.append(args)
+        return argmax(*args, **kw)
+
+    monkeypatch.setattr(jnp, "argmax", eager_argmax)
+    ep.execute({"seed": 1})
+    assert calls == ["prefill", "decode_loop"]
+    assert eager == []
 
 
 def test_execute_reports_its_waits_on_the_device(reduced_endpoint):
@@ -254,4 +288,5 @@ def test_execute_reports_its_waits_on_the_device(reduced_endpoint):
     (ex,) = obs.RECORDER.spans("inv.execute")
     out = inv.output
     assert ex.attrs["device_wait_s"] == out["device_wait_s"]
+    assert ex.attrs["host_syncs"] == out["host_syncs"] == 1
     assert 0.0 < out["device_wait_s"] < out["exec_s"] <= ex.end - ex.start

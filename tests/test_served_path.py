@@ -215,3 +215,32 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert "no TPU" in r.stderr
     assert '"ok"' not in r.stdout
     assert "endpoint init" not in r.stdout      # failed before any model
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "xlstm-350m",
+                                  "whisper-large-v3", "hymba-1.5b"])
+def test_execute_decodes_the_tokens_of_the_stepwise_path(arch):
+    """``execute`` runs the greedy decode loop as one device program and
+    reads its tokens once; they are the tokens of prefill followed by
+    ``decode_steps`` single decode steps, each fed its host argmax."""
+    import jax
+    import numpy as np
+
+    from repro.runtime.device import build_endpoints
+    ep = build_endpoints({"f": (arch, 0)}, serve_seq=8, serve_batch=2,
+                         decode_steps=3)["f"]
+    ep.compile(0)
+    for seed in (0, 5):
+        out = ep.execute({"seed": seed})
+        batch = ep.model.make_batch(ep.serve_shape,
+                                    rng=jax.random.PRNGKey(seed))
+        logits, cache = ep.prefill(batch)
+        pos, want = ep.prompt_len(batch), []
+        for i in range(ep.decode_steps):
+            tok = np.argmax(np.asarray(logits), -1)[:, None].astype(np.int32)
+            logits, cache = ep.decode(cache, tok, pos + i)
+            want.append(np.argmax(np.asarray(logits), -1)[:, None])
+        toks = out["tokens"]
+        assert toks.shape == (2, ep.decode_steps) and toks.dtype == np.int32
+        assert (toks == np.concatenate(want, axis=1)).all()
+        assert out["host_syncs"] == 1
