@@ -1,0 +1,9 @@
+"""Per cent of the traced window in which the chip ran nothing while an
+invocation waited or ran (device layer), in the cells that report
+``throughput_inv_s``; see
+``harness.program.device_idle_with_work_share``."""
+from harness import program
+
+
+def read(ctx):
+    return program.device_idle_with_work_share(ctx)

@@ -16,9 +16,10 @@ import pytest
 import control
 from harness import cell as cell_mod
 from harness import spec
+from tiny_configs import DATA, tiny_configs
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DATA = os.path.join(BENCH, "tests", "data")
+CONFIGS = [name for name, _ in tiny_configs()]
 
 
 def new_cell_root(tmp_path, config="tiny-qwen3"):
@@ -78,37 +79,41 @@ def test_the_benchmark_resolves_every_cell_and_metric():
         assert cell.config["gap_limit"] > 0
 
 
-def _tokens_altered(cls):
-    orig = cls.execute
+def _tokens_altered(monkeypatch):
+    from repro.runtime.device import JaxEndpoint
+    orig = JaxEndpoint.execute
 
     def execute(self, request=None, dev_id=0):
         out = orig(self, request, dev_id)
         out["tokens"][0, -1] = (out["tokens"][0, -1] + 1) % self.cfg.vocab_size
         return out
-    return "execute", execute
+    monkeypatch.setattr(JaxEndpoint, "execute", execute)
 
 
-def _state_unchanged(cls):
-    orig = cls.decode
+def _state_unchanged(monkeypatch):
+    from repro.models.model import Model
+    orig = Model.decode_fn
 
-    def decode(self, cache, tok, pos, dev_id=0):
-        logits, _ = orig(self, cache, tok, pos, dev_id)
+    def decode_fn(self, params, cache, tokens, pos, ring=False):
+        logits, _ = orig(self, params, cache, tokens, pos, ring)
         return logits, cache
-    return "decode", decode
+    monkeypatch.setattr(Model, "decode_fn", decode_fn)
 
 
-def _half_batch(cls):
-    orig = cls.execute
+def _half_batch(monkeypatch):
+    from repro.runtime.device import JaxEndpoint
+    orig = JaxEndpoint.execute
 
     def execute(self, request=None, dev_id=0):
         out = orig(self, request, dev_id)
         out["tokens"][1] = out["tokens"][0]
         return out
-    return "execute", execute
+    monkeypatch.setattr(JaxEndpoint, "execute", execute)
 
 
-def _wrong_weights(cls):
-    orig = cls.upload
+def _wrong_weights(monkeypatch):
+    from repro.runtime.device import JaxEndpoint
+    orig = JaxEndpoint.upload
     uploaded = []
 
     def upload(self, dev_id=0):
@@ -118,24 +123,22 @@ def _wrong_weights(cls):
             self.device_params[dev_id] = jax.device_put(others[-1].host_params)
         uploaded.append(self)
         return t
-    return "upload", upload
+    monkeypatch.setattr(JaxEndpoint, "upload", upload)
 
 
 @pytest.mark.parametrize("fault", [_tokens_altered, _state_unchanged,
                                    _half_batch, _wrong_weights])
-@pytest.mark.parametrize("config", ["tiny-qwen3", "tiny-xlstm"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_a_broken_timed_path_is_not_correct(tmp_path, monkeypatch, fault,
                                             config):
-    from repro.runtime.device import JaxEndpoint
-    name, fn = fault(JaxEndpoint)
-    monkeypatch.setattr(JaxEndpoint, name, fn)
+    fault(monkeypatch)
     cell = spec.load_cell("tiny.trickle", root=new_cell_root(tmp_path, config))
     out = tiny_run(cell)
     assert out["correct"] is False
     assert out["checks"]["token_gap"]["value"] > 1e-3
 
 
-@pytest.mark.parametrize("config", ["tiny-qwen3", "tiny-xlstm"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_control_reads_wider_gaps_than_the_program(config):
     cell = spec.Cell(name="t", chips=1, config_name=config,
                      config=spec.load_json(os.path.join(DATA, f"{config}.json")),

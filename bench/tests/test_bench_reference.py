@@ -1,5 +1,6 @@
-"""The references against the program at a small size on the CPU: the
-same weights from the same seed, bit for bit, and the same logits."""
+"""The references against the program at a small size on the CPU, for
+every tiny configuration: the same weights from the same seed, bit for
+bit, and the same logits."""
 import dataclasses
 import json
 import os
@@ -10,9 +11,9 @@ import numpy as np
 import pytest
 
 import reference
+from tiny_configs import DATA, tiny_configs
 
-DATA = os.path.join(os.path.dirname(__file__), "data")
-CASES = [("tiny-qwen3", "qwen3-1.7b"), ("tiny-xlstm", "xlstm-350m")]
+CASES = tiny_configs()
 
 
 def setup(name, arch, dtype):
@@ -51,6 +52,15 @@ def test_logits_equal_the_programs_forward(name, arch):
     got = fam.forward(conf, params, tokens, np.arange(24))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("name", [n for n, _ in CASES])
+def test_every_tiny_configuration_resolves(name):
+    from repro.configs import get_config
+    with open(os.path.join(DATA, f"{name}.json")) as f:
+        conf = json.load(f)
+    reference.family(conf["reference"])
+    get_config(conf["program"]["arch"])
 
 
 def test_prompt_is_the_served_requests_prompt():
