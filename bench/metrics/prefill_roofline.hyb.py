@@ -1,0 +1,8 @@
+"""Per cent of its roofline that the jitted prefill program of the
+Mamba-2 + attention stack reaches in the traced window (kernels layer);
+see ``harness.context.roofline``."""
+from harness.context import roofline
+
+
+def read(ctx):
+    return roofline(ctx, "prefill")

@@ -55,12 +55,13 @@ def step_cost(cfg: ModelConfig, shape: InputShape) -> CostTerms:
     N_active = cfg.n_active_params()
     N_total = cfg.n_params()
     L, KV, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    La = cfg.n_attention_layers        # layers that attend and keep KV
 
     if shape.kind == "train":
         tokens = B * S
         mm = 6.0 * N_active * tokens
         ctx = _window_ctx(cfg, S)
-        att = 3.0 * L * _attn_flops_layer(cfg, B, S, ctx, causal=True)
+        att = 3.0 * La * _attn_flops_layer(cfg, B, S, ctx, causal=True)
         if cfg.family == "audio":
             att += 3.0 * cfg.n_encoder_layers * _attn_flops_layer(
                 cfg, B, cfg.encoder_len, cfg.encoder_len, causal=False)
@@ -75,13 +76,13 @@ def step_cost(cfg: ModelConfig, shape: InputShape) -> CostTerms:
         tokens = B * S
         mm = 2.0 * N_active * tokens
         ctx = _window_ctx(cfg, S)
-        att = L * _attn_flops_layer(cfg, B, S, ctx, causal=True)
+        att = La * _attn_flops_layer(cfg, B, S, ctx, causal=True)
         if cfg.family == "audio":
             att += cfg.n_encoder_layers * _attn_flops_layer(
                 cfg, B, cfg.encoder_len, cfg.encoder_len, causal=False)
             att += L * _attn_flops_layer(cfg, B, S, cfg.encoder_len,
                                          causal=False)
-        kvb = 2.0 * L * B * min(S, _window_ctx(cfg, S)) * KV * dh * ds
+        kvb = 2.0 * La * B * min(S, _window_ctx(cfg, S)) * KV * dh * ds
         act = 2.0 * tokens * cfg.d_model * L * ds
         return CostTerms(mm + att, N_active * ds, kvb, act)
 
@@ -97,11 +98,11 @@ def step_cost(cfg: ModelConfig, shape: InputShape) -> CostTerms:
                          2 * B * cfg.d_model * cfg.n_layers * ds)
     ctx = _window_ctx(cfg, S)
     mm = 2.0 * N_active * tokens
-    att = L * _attn_flops_layer(cfg, B, 1, ctx, causal=False)
+    att = La * _attn_flops_layer(cfg, B, 1, ctx, causal=False)
     # read full (windowed) cache; int8 KV (§Perf H5) reads 1 byte/elem
     # + one f32 scale per (token, kv-head)
     kv_elem = (1.0 + 4.0 / dh) if cfg.kv_quant else float(ds)
-    kvb = 2.0 * L * B * ctx * KV * dh * kv_elem
+    kvb = 2.0 * La * B * ctx * KV * dh * kv_elem
     if cfg.family == "audio":
         att += L * _attn_flops_layer(cfg, B, 1, cfg.encoder_len,
                                      causal=False)
@@ -109,6 +110,9 @@ def step_cost(cfg: ModelConfig, shape: InputShape) -> CostTerms:
     if cfg.family == "hybrid":
         state = B * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
         kvb += 2.0 * L * state
+    if cfg.layer_types:     # Mamba-2 layers: f32 SSM state read and written
+        state = B * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+        kvb += 2.0 * (L - La) * state
     # MoE decode touches min(E, tokens*k) experts' weights
     wbytes = N_active * ds
     if cfg.is_moe:

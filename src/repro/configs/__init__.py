@@ -17,6 +17,7 @@ _MODULES = {
     "deepseek-coder-33b": "deepseek_coder_33b",
     "xlstm-350m": "xlstm_350m",
     "hymba-1.5b": "hymba_1_5b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

@@ -47,6 +47,20 @@ class ModelConfig:
     ssm_head_dim: int = 0
     conv_width: int = 4
 
+    # Mamba-2 + attention stack (family mamba2): one kind per layer,
+    # "mamba" or "attention", in the published order
+    layer_types: Tuple[str, ...] = ()
+    rope: bool = True              # False: no positional encoding (NoPE)
+    # muP multipliers: embeddings times embedding_multiplier, each
+    # branch's output times residual_multiplier, attention scores times
+    # attention_multiplier (0: head_dim ** -0.5), logits over
+    # logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6
+
     # xLSTM
     mlstm_proj_factor: float = 2.0
     slstm_proj_factor: float = 4.0 / 3.0
@@ -80,6 +94,12 @@ class ModelConfig:
         assert self.n_heads % max(self.n_kv_heads, 1) == 0, (
             f"{self.arch_id}: n_heads {self.n_heads} not divisible by "
             f"n_kv_heads {self.n_kv_heads}")
+        if self.layer_types:
+            assert len(self.layer_types) == self.n_layers, (
+                f"{self.arch_id}: {len(self.layer_types)} layer types for "
+                f"{self.n_layers} layers")
+            assert set(self.layer_types) <= {"mamba", "attention"}, (
+                f"{self.arch_id}: unknown layer types {self.layer_types}")
 
     # -- derived -----------------------------------------------------------
     @property
@@ -93,6 +113,12 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def n_attention_layers(self) -> int:
+        if self.layer_types:
+            return self.layer_types.count("attention")
+        return self.n_layers
 
     @property
     def is_moe(self) -> bool:
@@ -117,6 +143,21 @@ class ModelConfig:
         attn = d * (H * dh) + 2 * d * (KV * dh) + (H * dh) * d
         if self.qkv_bias:
             attn += (H + 2 * KV) * dh
+        if self.layer_types:
+            # Mamba-2 layers: fused in_proj (z, xBC, dt), conv and its
+            # bias, dt_bias, A_log, D, gated norm, out_proj; every layer
+            # an MLP
+            Hs, di = self.ssm_heads, self.ssm_heads * self.ssm_head_dim
+            conv = di + 2 * self.ssm_state
+            mamba = (d * (di + conv + Hs) + conv * (self.conv_width + 1)
+                     + 3 * Hs + di + di * d)
+            n_attn = self.n_attention_layers
+            total = (n_attn * attn + (L - n_attn) * mamba
+                     + L * (3 * d * self.d_ff + 2 * d)
+                     + self.vocab_size * d + d)
+            if not self.tie_embeddings:
+                total += self.vocab_size * d
+            return total
         if self.is_moe:
             ffn = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
         elif self.family == "ssm":
@@ -172,6 +213,18 @@ class ModelConfig:
         if self.family == "hybrid":
             kw.update(ssm_heads=min(self.ssm_heads, 2), ssm_head_dim=32,
                       ssm_state=min(self.ssm_state, 8))
+        if self.layer_types:    # one layer of each kind, in order
+            kinds = tuple(dict.fromkeys(self.layer_types))
+            # embeddings drawn at vocab ** -0.5 are larger in the small
+            # vocabulary: scale the multiplier down to match, or the
+            # token's own embedding outweighs the layers and the greedy
+            # token ignores the context
+            emb = self.embedding_multiplier * (
+                kw["vocab_size"] / self.vocab_size) ** 0.5
+            kw.update(n_layers=len(kinds), layer_types=kinds,
+                      ssm_heads=min(self.ssm_heads, 8), ssm_head_dim=32,
+                      ssm_state=min(self.ssm_state, 16),
+                      embedding_multiplier=emb)
         if self.n_encoder_layers:
             kw.update(n_encoder_layers=2, encoder_len=32, max_positions=128)
         if self.n_patches:

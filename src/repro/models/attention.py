@@ -59,14 +59,15 @@ def masked_attention(q, k, v, q_pos, k_pos, *, causal: bool = True,
 
 
 def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool = True,
-                      window: int = 0, chunk: int = 1024):
+                      window: int = 0, chunk: int = 1024,
+                      scale: Optional[float] = None):
     """Query-chunked attention: peak memory O(chunk * Sk) instead of
     O(Sq * Sk). Used for long prefill (32k) where the full score matrix
     would not fit per-chip HBM."""
     B, Sq, H, dh = q.shape
     if Sq % chunk or Sq <= chunk:
         return masked_attention(q, k, v, q_pos, k_pos,
-                                causal=causal, window=window)
+                                causal=causal, window=window, scale=scale)
     n = Sq // chunk
     qs = q.reshape(B, n, chunk, H, dh).transpose(1, 0, 2, 3, 4)
     ps = q_pos.reshape(n, chunk)
@@ -79,7 +80,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool = True,
     def one(args):
         qc, pc = args
         return masked_attention(qc, k, v, pc, k_pos,
-                                causal=causal, window=window)
+                                causal=causal, window=window, scale=scale)
 
     out = jax.lax.map(one, (qs, ps))  # (n, B, chunk, H, dh)
     return out.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, dh)
@@ -135,7 +136,7 @@ def cache_write_ring(cache_k, cache_v, k, v, pos):
 
 
 def decode_attention(q, cache_k, cache_v, pos, *, window: int = 0,
-                     ring: bool = False):
+                     ring: bool = False, scale: Optional[float] = None):
     """Single-position decode: q (B, 1, H, dh) against a cache.
 
     ``pos`` is the absolute position of the query token; the cache holds
@@ -150,4 +151,4 @@ def decode_attention(q, cache_k, cache_v, pos, *, window: int = 0,
         k_pos = jnp.where(jnp.arange(Smax) <= pos, jnp.arange(Smax), -1)
     q_pos = jnp.full((1,), pos, jnp.int32)
     return masked_attention(q, cache_k, cache_v, q_pos, k_pos,
-                            causal=True, window=window)
+                            causal=True, window=window, scale=scale)

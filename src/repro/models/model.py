@@ -6,6 +6,7 @@
   - prefill_fn(params, batch)                    (prompt -> cache)
   - decode_fn(params, cache, tokens, pos)        (serve_step)
   - cache/batch shape planning per assigned input shape
+  - ``cache_bytes(cache)``: a serve cache's bytes by kind (KV or state)
 """
 from __future__ import annotations
 
@@ -29,6 +30,22 @@ class CachePlan:
     @property
     def ring(self) -> bool:
         return self.kind == "ring"
+
+
+# cache leaves that hold attention keys and values (and their int8 scales)
+KV_LEAVES = frozenset({"k", "v", "ck", "cv", "k_scale", "v_scale",
+                       "ck_scale", "cv_scale"})
+
+
+def cache_bytes(cache) -> Dict[str, int]:
+    """Bytes of a serve cache by kind: ``kv_bytes`` (attention keys and
+    values, with their scales) and ``state_bytes`` (recurrent and conv
+    state: every other leaf)."""
+    out = {"kv_bytes": 0, "state_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        kv = any(getattr(k, "key", None) in KV_LEAVES for k in path)
+        out["kv_bytes" if kv else "state_bytes"] += leaf.nbytes
+    return out
 
 
 def decode_cache_plan(cfg: ModelConfig, seq_len: int) -> CachePlan:

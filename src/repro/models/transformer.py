@@ -1,4 +1,5 @@
-"""Decoder-only transformer stack: dense / MoE / hybrid(attn+SSM) / VLM.
+"""Decoder-only transformer stack: dense / MoE / hybrid(attn+SSM) / VLM,
+and Mamba-2 + attention layers in a fixed pattern (mamba2).
 
 Layers are stacked on a leading L dim and scanned (compile time is depth-
 independent). Modes:
@@ -24,16 +25,14 @@ from repro.utils.shardctx import batch_axis, maybe_shard
 PREFILL_CHUNK = 1024
 
 
-def decoder_param_table(cfg: ModelConfig) -> Dict:
+def _attn_param_table(cfg: ModelConfig, L: int) -> Dict[str, ParamDef]:
     d, dh = cfg.d_model, cfg.head_dim
-    H, KV, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
+    H, KV = cfg.n_heads, cfg.n_kv_heads
     layers: Dict[str, ParamDef] = {
-        "ln1": ParamDef((L, d), (None, None), init="ones"),
         "wq": ParamDef((L, d, H * dh), (None, None, "model")),
         "wk": ParamDef((L, d, KV * dh), (None, None, "model")),
         "wv": ParamDef((L, d, KV * dh), (None, None, "model")),
         "wo": ParamDef((L, H * dh, d), (None, "model", None)),
-        "ln2": ParamDef((L, d), (None, None), init="ones"),
     }
     if cfg.qkv_bias:
         layers["bq"] = ParamDef((L, H * dh), (None, "model"), init="zeros")
@@ -42,6 +41,17 @@ def decoder_param_table(cfg: ModelConfig) -> Dict:
     if cfg.qk_norm:
         layers["q_norm"] = ParamDef((L, dh), (None, None), init="ones")
         layers["k_norm"] = ParamDef((L, dh), (None, None), init="ones")
+    return layers
+
+
+def decoder_param_table(cfg: ModelConfig) -> Dict:
+    d, L = cfg.d_model, cfg.n_layers
+    layers: Dict[str, ParamDef] = {
+        "ln1": ParamDef((L, d), (None, None), init="ones"),
+        "ln2": ParamDef((L, d), (None, None), init="ones"),
+    }
+    if not cfg.layer_types:
+        layers.update(_attn_param_table(cfg, L))
     if cfg.is_moe:
         layers.update(moe_mod.moe_param_table(cfg, L))
     else:
@@ -57,6 +67,13 @@ def decoder_param_table(cfg: ModelConfig) -> Dict:
         "layers": layers,
         "final_norm": ParamDef((d,), (None,), init="ones"),
     }
+    if cfg.layer_types:
+        # each kind's mixers on a stack of their own; norms and MLPs in
+        # ``layers``, one per layer
+        table["attention"] = _attn_param_table(
+            cfg, cfg.n_attention_layers)
+        table["mamba"] = ssm_mod.mamba2_param_table(
+            cfg, cfg.n_layers - cfg.n_attention_layers)
     if not cfg.tie_embeddings:
         table["lm_head"] = ParamDef((d, cfg.vocab_size), (None, "model"))
     return table
@@ -79,8 +96,9 @@ def _qkv(cfg: ModelConfig, p, xn, positions):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
-    q = rope(q, positions, cfg.rope_theta, partial=cfg.rope_2d)
-    k = rope(k, positions, cfg.rope_theta, partial=cfg.rope_2d)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta, partial=cfg.rope_2d)
+        k = rope(k, positions, cfg.rope_theta, partial=cfg.rope_2d)
     return q, k, v
 
 
@@ -94,6 +112,7 @@ def _attn_branch(cfg: ModelConfig, p, xn, layer_cache, pos, mode,
                  ring: bool):
     B, S, _ = xn.shape
     window = cfg.sliding_window
+    scale = cfg.attention_multiplier or None
     if mode == "train":
         positions = jnp.arange(S)
         q, k, v = _qkv(cfg, p, xn, positions)
@@ -101,10 +120,11 @@ def _attn_branch(cfg: ModelConfig, p, xn, layer_cache, pos, mode,
         if chunk:
             out = attn.chunked_attention(q, k, v, positions, positions,
                                          causal=True, window=window,
-                                         chunk=chunk)
+                                         chunk=chunk, scale=scale)
         else:
             out = attn.masked_attention(q, k, v, positions, positions,
-                                        causal=True, window=window)
+                                        causal=True, window=window,
+                                        scale=scale)
         new_cache = None
     elif mode == "prefill":
         positions = jnp.arange(S)
@@ -113,10 +133,11 @@ def _attn_branch(cfg: ModelConfig, p, xn, layer_cache, pos, mode,
         if chunk:
             out = attn.chunked_attention(q, k, v, positions, positions,
                                          causal=True, window=window,
-                                         chunk=chunk)
+                                         chunk=chunk, scale=scale)
         else:
             out = attn.masked_attention(q, k, v, positions, positions,
-                                        causal=True, window=window)
+                                        causal=True, window=window,
+                                        scale=scale)
         ck, cv = layer_cache["k"], layer_cache["v"]
         if cfg.kv_quant:
             k, sk = attn.quantize_kv(k)
@@ -162,7 +183,8 @@ def _attn_branch(cfg: ModelConfig, p, xn, layer_cache, pos, mode,
             # attention dots, so HBM traffic is the int8 bytes (§Perf H5)
             ck = attn.dequantize_kv(ck, cks, cfg.compute_dtype)
             cv = attn.dequantize_kv(cv, cvs, cfg.compute_dtype)
-        out = attn.decode_attention(q, ck, cv, pos, window=window, ring=ring)
+        out = attn.decode_attention(q, ck, cv, pos, window=window, ring=ring,
+                                    scale=scale)
     out = out.reshape(B, S, cfg.n_heads * cfg.head_dim)
     out = maybe_shard(out, batch_axis(), None, "model")
     return out @ p["wo"], new_cache
@@ -203,12 +225,83 @@ def block_apply(cfg: ModelConfig, p, x, layer_cache, pos, mode,
     return x, (new_cache if mode != "train" else None), aux
 
 
+# --- Mamba-2 + attention pattern (mamba2) ----------------------------------------
+
+def layer_runs(layer_types: Tuple[str, ...]):
+    """The shortest period that repeats to the whole pattern, as
+    (number of periods, ((kind, count), ...) runs of one period)."""
+    L = len(layer_types)
+    p = next(p for p in range(1, L + 1)
+             if L % p == 0 and layer_types == layer_types[:p] * (L // p))
+    runs = []
+    for kind in layer_types[:p]:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return L // p, tuple((k, n) for k, n in runs)
+
+
+def _pattern_layer(cfg: ModelConfig, params, kind: str, pos, mode: str,
+                   ring: bool, carry, idx):
+    """Layer ``i`` of the pattern, the ``j``-th of its kind:
+    ``h = x + r * mixer(rmsnorm(x))``, ``x = h + r * mlp(rmsnorm(h))``.
+    It reads its weights and cache from the stacks by index and writes
+    its cache back in place."""
+    x, cache = carry
+    i, j = idx
+    at = lambda tree, k: jax.tree.map(lambda a: a[k], tree)
+    pl, pk = at(params["layers"], i), at(params[kind], j)
+    c = None if cache is None else at(cache[kind], j)
+    r, eps = cfg.residual_multiplier, cfg.norm_eps
+    xn = rms_norm(x, pl["ln1"], eps)
+    if kind == "mamba":
+        if c is None:       # train: from zero state
+            c = jax.tree.map(lambda sd: jnp.zeros(*sd),
+                             ssm_mod.mamba2_state_shapes(cfg, x.shape[0]),
+                             is_leaf=_is_shape)
+        y, ssm, conv = ssm_mod.mamba2_apply(cfg, pk, xn, c["ssm"], c["conv"])
+        c = {"ssm": ssm, "conv": conv}
+    else:
+        y, c = _attn_branch(cfg, pk, xn, c, pos, mode, ring)
+    h = x + r * y
+    x = h + r * _mlp(cfg, pl, rms_norm(h, pl["ln2"], eps))
+    if cache is not None:
+        cache = dict(cache, **{kind: jax.tree.map(
+            lambda a, b: a.at[j].set(b.astype(a.dtype)), cache[kind], c)})
+    return (x, cache), None
+
+
+def pattern_stack(cfg: ModelConfig, params, x, cache, pos, mode: str,
+                  ring: bool):
+    """``x`` through the layers of ``cfg.layer_types``: one scan over the
+    pattern's periods and, in each, one scan per run of same-kind
+    layers, so compile time does not grow with depth. Returns (x, cache);
+    ``cache`` is None in train mode."""
+    n_per, runs = layer_runs(cfg.layer_types)
+    period = sum(n for _, n in runs)
+    per_kind = {k: sum(n for kk, n in runs if kk == k) for k, _ in runs}
+
+    def one_period(carry, p):
+        i0, done = 0, dict.fromkeys(per_kind, 0)
+        for kind, n in runs:
+            body = partial(_pattern_layer, cfg, params, kind, pos, mode, ring)
+            if mode == "train":
+                body = jax.checkpoint(body)
+            idx = (p * period + i0 + jnp.arange(n),
+                   p * per_kind[kind] + done[kind] + jnp.arange(n))
+            carry, _ = jax.lax.scan(body, carry, idx)
+            i0, done[kind] = i0 + n, done[kind] + n
+        return carry, None
+
+    (x, cache), _ = jax.lax.scan(one_period, (x, cache), jnp.arange(n_per))
+    return x, cache
+
+
 # --- cache --------------------------------------------------------------------
 
-def cache_shapes(cfg: ModelConfig, batch: int, cache_len: int,
-                 ring: bool) -> Dict:
-    """Shapes/dtypes of the serve cache (leading dim L on every leaf)."""
-    L, KV, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+def _kv_shapes(cfg: ModelConfig, L: int, batch: int, cache_len: int) -> Dict:
+    KV, dh = cfg.n_kv_heads, cfg.head_dim
     dt = jnp.int8 if cfg.kv_quant else cfg.compute_dtype
     shapes = {
         "k": ((L, batch, cache_len, KV, dh), dt),
@@ -217,6 +310,23 @@ def cache_shapes(cfg: ModelConfig, batch: int, cache_len: int,
     if cfg.kv_quant:
         shapes["k_scale"] = ((L, batch, cache_len, KV), jnp.float32)
         shapes["v_scale"] = ((L, batch, cache_len, KV), jnp.float32)
+    return shapes
+
+
+def cache_shapes(cfg: ModelConfig, batch: int, cache_len: int,
+                 ring: bool) -> Dict:
+    """Shapes/dtypes of the serve cache (leading dim L on every leaf).
+    A Mamba-2 + attention stack keeps two groups: ``attention`` (KV of
+    its attention layers) and ``mamba`` (conv and SSM state of the
+    others)."""
+    L = cfg.n_layers
+    if cfg.layer_types:
+        La = cfg.n_attention_layers
+        states = ssm_mod.mamba2_state_shapes(cfg, batch)
+        return {"attention": _kv_shapes(cfg, La, batch, cache_len),
+                "mamba": {name: ((L - La,) + s, d)
+                          for name, (s, d) in states.items()}}
+    shapes = _kv_shapes(cfg, L, batch, cache_len)
     if cfg.family == "hybrid":
         st = ssm_mod.ssm_state_shapes(cfg, batch)
         for name, (s, d) in st.items():
@@ -227,30 +337,40 @@ def cache_shapes(cfg: ModelConfig, batch: int, cache_len: int,
 def zero_cache(cfg: ModelConfig, batch: int, cache_len: int, ring: bool,
                abstract: bool = False):
     shapes = cache_shapes(cfg, batch, cache_len, ring)
-    if abstract:
-        return {k: jax.ShapeDtypeStruct(s, d) for k, (s, d) in shapes.items()}
-    return {k: jnp.zeros(s, d) for k, (s, d) in shapes.items()}
+    mk = jax.ShapeDtypeStruct if abstract else jnp.zeros
+    return jax.tree.map(lambda sd: mk(*sd), shapes, is_leaf=_is_shape)
+
+
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
 # --- full stack ----------------------------------------------------------------
 
 def _embed(cfg: ModelConfig, params, tokens, patch_embeds=None):
     x = params["emb"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if patch_embeds is not None:
         x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
     return maybe_shard(x.astype(cfg.compute_dtype), batch_axis())
 
 
 def _unembed(cfg: ModelConfig, params, x):
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["emb"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head.astype(x.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return maybe_shard(logits, batch_axis(), None, "model")
 
 
 def forward(cfg: ModelConfig, params, tokens, patch_embeds=None):
     """Teacher-forced logits over the full sequence (training)."""
     x = _embed(cfg, params, tokens, patch_embeds)
+    if cfg.layer_types:
+        x, _ = pattern_stack(cfg, params, x, None, 0, "train", False)
+        return _unembed(cfg, params, x), jnp.zeros((), jnp.float32)
 
     block = partial(block_apply, cfg, mode="train", pos=0, ring=False,
                     layer_cache=None)
@@ -286,7 +406,10 @@ def prefill(cfg: ModelConfig, params, tokens, patch_embeds=None,
                                       "prefill", ring)
         return x, new_cache
 
-    x, cache = jax.lax.scan(scan_body, x, (params["layers"], cache))
+    if cfg.layer_types:
+        x, cache = pattern_stack(cfg, params, x, cache, 0, "prefill", ring)
+    else:
+        x, cache = jax.lax.scan(scan_body, x, (params["layers"], cache))
     logits = _unembed(cfg, params, x[:, -1:])
     return logits[:, 0], cache
 
@@ -302,6 +425,9 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos,
                                       "decode", ring)
         return x, new_cache
 
-    x, cache = jax.lax.scan(scan_body, x, (params["layers"], cache))
+    if cfg.layer_types:
+        x, cache = pattern_stack(cfg, params, x, cache, pos, "decode", ring)
+    else:
+        x, cache = jax.lax.scan(scan_body, x, (params["layers"], cache))
     logits = _unembed(cfg, params, x)
     return logits[:, 0], cache

@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.models import CachePlan, Model, build_model, decode_cache_plan
+from repro.models.model import cache_bytes
 from repro.shapes import InputShape
 
 # fixed, so that one checkout's runs find each other's compiles
@@ -61,8 +62,6 @@ def served_programs(model: Model, plan: CachePlan,
     (prefill's logits and cache to ``decode_steps`` greedy tokens)."""
 
     def _prefill(params, batch):
-        if plan.kind == "state":
-            return model.prefill_fn(params, batch)
         return model.prefill_fn(params, batch, cache_len=plan.length,
                                 ring=plan.ring)
 
@@ -175,7 +174,10 @@ class JaxEndpoint:
         one read back. ``device`` is where the logits were made,
         ``weight_devices`` where the weights it read live;
         ``device_wait_s`` is the time spent blocked on that read and
-        ``host_syncs`` the number of blocking reads."""
+        ``host_syncs`` the number of blocking reads; ``kv_bytes`` and
+        ``state_bytes`` are the bytes of attention KV and of recurrent
+        state in the cache the decode loop carried (0 for a kind the
+        model lacks)."""
         assert self.resident_on(dev_id) and self.compiled
         t0 = time.monotonic()
         with jax.default_device(jax.devices()[dev_id]):
@@ -192,6 +194,7 @@ class JaxEndpoint:
         return {"exec_s": time.monotonic() - t0,
                 "device_wait_s": waited,
                 "host_syncs": 1,
+                **cache_bytes(cache),
                 "tokens": tokens,
                 "device": next(iter(logits.devices())),
                 "weight_devices": {d for leaf in jax.tree.leaves(params)

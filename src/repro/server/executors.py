@@ -899,8 +899,10 @@ class WallClockExecutor:
                         # stubs wait on no device: they report none
                         sp.attrs["device_wait_s"] = out.get(
                             "device_wait_s", 0.0)
-                        if "host_syncs" in out:
-                            sp.attrs["host_syncs"] = out["host_syncs"]
+                        for key in ("host_syncs", "kv_bytes",
+                                    "state_bytes"):
+                            if key in out:
+                                sp.attrs[key] = out[key]
                     done = sp.end
                     inv.service_time = out["exec_s"]
                     inv.output = out

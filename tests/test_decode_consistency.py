@@ -25,7 +25,8 @@ def _forward_logits(cfg, m, params, tokens):
 
 @pytest.mark.parametrize("arch", ["qwen3-1.7b", "chatglm3-6b",
                                   "qwen1.5-32b", "deepseek-coder-33b",
-                                  "qwen3-moe-30b-a3b", "xlstm-350m"])
+                                  "qwen3-moe-30b-a3b", "xlstm-350m",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_forward(arch):
     cfg = get_config(arch).reduced()
     m = build_model(cfg)

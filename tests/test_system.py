@@ -80,7 +80,7 @@ def test_run_sim_shim_matches_new_api(medium_workload):
 def test_endpoint_specs_reasonable():
     for shape in ["decode_32k", "prefill_32k"]:
         mix = endpoint_mix(shape)
-        assert len(mix) == 10
+        assert len(mix) == 11
         for s in mix.values():
             assert 0 < s.warm_time < 300
             assert s.cold_init > 1.0
@@ -98,7 +98,7 @@ def test_endpoint_serving_sim():
 def test_long500k_mix_excludes_whisper():
     mix = endpoint_mix("long_500k")
     assert not any("whisper" in k for k in mix)
-    assert len(mix) == 9
+    assert len(mix) == 10
 
 
 @pytest.mark.slow
