@@ -1,10 +1,14 @@
 """Selective-state-space (Mamba-style) heads: Hymba's SSM branch (family
 hybrid) and the Mamba-2 mixer (family mamba2).
 
-Per-head scalar decay A, state size N (=cfg.ssm_state), depthwise causal
-conv front-end. Training/prefill uses a two-level chunked time scan (outer
-carry = state at chunk boundaries, inner steps rematerialized) so reverse-
-mode does not checkpoint every timestep.
+Per-head scalar decay A, state size N (=cfg.ssm_state), one group of B
+and C shared by every head, depthwise causal conv front-end. The time
+recurrence ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t``,
+``y_t = h_t C_t`` runs in float32 by sequence length: one ``_ssm_step``
+for a single position (every decode step), and the chunked SSD form
+(state space duality, Mamba-2 paper §6) for more (train and prefill):
+matmuls inside chunks of ``CHUNK`` positions, and one decay matmul
+across the chunk states, with no loop over time.
 """
 from __future__ import annotations
 
@@ -17,7 +21,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamDef
 
-CHUNK = 64
+# positions per chunk of the SSD form (chip measurement, PERF.md §5)
+CHUNK = 128
 
 
 def ssm_param_table(cfg: ModelConfig, L: int) -> Dict[str, ParamDef]:
@@ -59,24 +64,63 @@ def _ssm_step(state, inputs, A):
     return state, y
 
 
+def _segsum(a):
+    """a (..., T) -> (..., T, T): ``out[i, j] = a[j+1] + ... + a[i]`` for
+    i >= j, -inf above the diagonal: a masked sum of the terms, not a
+    difference of long cumsums, so it stays exact where the decay is
+    strong; the sum is one float32 matmul with a triangle of ones."""
+    T = a.shape[-1]
+    ones = jnp.ones((T, T), a.dtype)
+    x = a[..., :, None] * jnp.tril(ones, -1)         # x[k, j] = a[k], k > j
+    x = jnp.einsum("ik,...kj->...ij", jnp.tril(ones), x,
+                   precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(jnp.tril(ones) > 0, x, -jnp.inf)
+
+
+def _ssd_scan(state, xs, A):
+    """The recurrence of ``_ssm_step`` over the leading (time) axis of
+    ``xs`` in the chunked SSD form: (final state, ys (S,B,Hs,P)). S is
+    padded up to a multiple of the chunk with dt = 0 and x = B = C = 0,
+    so a padded position neither decays nor updates the state."""
+    x, dt, Bm, Cm = xs
+    S = x.shape[0]
+    Q = min(CHUNK, S)
+    nc = -(-S // Q)
+    pad = nc * Q - S
+    chunks = lambda v: jnp.pad(v, [(0, pad)] + [(0, 0)] * (v.ndim - 1)
+                               ).reshape(nc, Q, *v.shape[1:])
+    x, dt, Bm, Cm = map(chunks, (x, dt, Bm, Cm))      # (c,l,b,...)
+    ein = partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    a = dt * A                                        # (c,l,b,h)
+    a_cum = jnp.cumsum(a, axis=1)
+    xdt = x * dt[..., None]                           # (c,l,b,h,p)
+    # inside each chunk: ((C B^T) o L) @ (dt x), C B^T shared by heads
+    decay = jnp.exp(_segsum(jnp.moveaxis(a, 1, -1)))  # L: (c,b,h,l,s)
+    cb = ein("clbn,csbn->cbls", Cm, Bm)
+    y = ein("cbhls,csbhp->clbhp", cb[:, :, None] * decay, xdt)
+    # each chunk's end state from zero (L's last row decays a position
+    # to the chunk's end), then chunk to chunk from `state`
+    to_end = jnp.moveaxis(decay[..., -1, :], -1, 1)  # (c,l,b,h)
+    ends = ein("clbhp,clbn->cbhpn", xdt * to_end[..., None], Bm)
+    ends = jnp.concatenate([state[None], ends])       # (c+1,b,h,p,n)
+    a_chunk = jnp.pad(jnp.moveaxis(a_cum[:, -1], 0, -1), [(0, 0), (0, 0),
+                                                           (1, 0)])
+    across = jnp.exp(_segsum(a_chunk))                # (b,h,c+1,c+1)
+    starts = ein("bhzc,cbhpn->zbhpn", across, ends)
+    # from each chunk's start state
+    y = y + ein("clbn,cbhpn->clbhp", Cm, starts[:-1]) \
+        * jnp.exp(a_cum)[..., None]
+    return starts[-1], y.reshape(nc * Q, *y.shape[2:])[:S]
+
+
 def _time_scan(state, xs, A):
-    """``_ssm_step`` over the leading (time) axis of ``xs``: ys (S,B,Hs,P).
-    A multiple of CHUNK steps scans by chunks, each rematerialized under
-    reverse mode."""
-    S = xs[0].shape[0]
-    step = partial(_ssm_step, A=A)
-    if S % CHUNK == 0 and S > CHUNK:
-        n = S // CHUNK
-
-        @jax.checkpoint
-        def chunk_fn(st, chunk_xs):
-            return jax.lax.scan(step, st, chunk_xs)
-
-        cxs = jax.tree.map(
-            lambda a: a.reshape(n, CHUNK, *a.shape[1:]), xs)
-        state, ys = jax.lax.scan(chunk_fn, state, cxs)
-        return state, ys.reshape(S, *ys.shape[2:])
-    return jax.lax.scan(step, state, xs)
+    """The recurrence over the leading (time) axis of ``xs``: (final
+    state, ys (S,B,Hs,P)). One ``_ssm_step`` for S == 1 (decode); the
+    chunked SSD form otherwise."""
+    if xs[0].shape[0] == 1:
+        state, y = _ssm_step(state, [v[0] for v in xs], A)
+        return state, y[None]
+    return _ssd_scan(state, xs, A)
 
 
 def ssm_apply_seq(cfg: ModelConfig, p, x, state, conv_state):
