@@ -345,12 +345,13 @@ class DeviceDataPath:
         self.n_prefetch = 0
         return n
 
-    def on_region_evicted(self, fn_id: str) -> None:
-        """Memory-manager evict listener: a prefetch-in-flight region
+    def on_residency(self, fn_id: str, resident: bool) -> None:
+        """Memory-manager residency listener: a prefetch-in-flight region
         was reclaimed under pressure — abort its transfer. (Regions of
         dispatched transfers have waiters, so ``cancel`` refuses and the
         upload keeps accounting/reality reconcilable at completion.)"""
-        self.cancel(fn_id, self.now)
+        if not resident:
+            self.cancel(fn_id, self.now)
 
     # -- event-loop surface -------------------------------------------------
     def next_eta(self) -> Optional[float]:

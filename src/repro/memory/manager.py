@@ -86,9 +86,9 @@ class DeviceMemoryManager:
         # demand uploads are unaffected
         self.anticipatory_upload = True
         self.regions: Dict[str, Region] = {}
-        # notified with fn_id whenever a region is swapped out; the
-        # wall-clock executor mirrors these onto real endpoints
-        self.evict_listeners: List = []
+        # cb(fn_id, resident) at every reservation and eviction: the
+        # wall-clock executor's weight copies follow these
+        self.residency_listeners: List = []
         # accounting
         self.bytes_uploaded = 0
         self.bytes_evicted = 0
@@ -119,6 +119,8 @@ class DeviceMemoryManager:
             r.resident = resident
             if resident:
                 self._reindex(r)
+        for cb in self.residency_listeners:
+            cb(r.fn_id, resident)
 
     def _reindex(self, r: Region) -> None:
         """Push fresh heap entries for a region whose LRU key (residency,
@@ -155,10 +157,9 @@ class DeviceMemoryManager:
 
     # -- eviction -----------------------------------------------------------
     def _evict_one(self, r: Region) -> None:
-        self._set_resident(r, False)
         r.upload_eta = -1.0
         self.bytes_evicted += r.size
-        self._notify_evict(r.fn_id)
+        self._set_resident(r, False)
 
     def _evict_lru(self, need: int, now: float,
                    protect: Tuple[str, ...] = (),
@@ -273,10 +274,6 @@ class DeviceMemoryManager:
         for e in skipped:
             heapq.heappush(h, e)
         return self.free_bytes() >= need
-
-    def _notify_evict(self, fn_id: str) -> None:
-        for cb in self.evict_listeners:
-            cb(fn_id)
 
     def _upload_eta(self, fn_id: str, size: int, now: float,
                     kind: str) -> float:

@@ -38,9 +38,9 @@ class ReferenceDeviceMemoryManager:
         self.h2d_bw = h2d_bw
         self.policy = policy
         self.regions: Dict[str, Region] = {}
-        # notified with fn_id whenever a region is swapped out; the
-        # wall-clock executor mirrors these onto real endpoints
-        self.evict_listeners: List = []
+        # cb(fn_id, resident) at every reservation and eviction: the
+        # wall-clock executor's weight copies follow these
+        self.residency_listeners: List = []
         # accounting
         self.bytes_uploaded = 0
         self.bytes_evicted = 0
@@ -63,6 +63,8 @@ class ReferenceDeviceMemoryManager:
         if r.resident != resident:
             self._used += r.size if resident else -r.size
             r.resident = resident
+        for cb in self.residency_listeners:
+            cb(r.fn_id, resident)
 
     @property
     def used(self) -> int:
@@ -90,14 +92,9 @@ class ReferenceDeviceMemoryManager:
                 self._set_resident(r, False)
                 r.upload_eta = -1.0
                 self.bytes_evicted += r.size
-                self._notify_evict(r.fn_id)
                 if self.free_bytes() >= need:
                     return True
         return self.free_bytes() >= need
-
-    def _notify_evict(self, fn_id: str) -> None:
-        for cb in self.evict_listeners:
-            cb(fn_id)
 
     # -- scheduler hooks ------------------------------------------------------
     def on_queue_active(self, fn_id: str, size: int, now: float) -> None:
@@ -127,7 +124,6 @@ class ReferenceDeviceMemoryManager:
             if r.resident and r.upload_eta <= now:
                 self._set_resident(r, False)
                 self.bytes_evicted += r.size
-                self._notify_evict(r.fn_id)
 
     # -- dispatch-time ---------------------------------------------------------
     def admit(self, fn_id: str, size: int, running, now: float) -> bool:

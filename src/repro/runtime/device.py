@@ -10,10 +10,10 @@ abstract "regions" map to real bytes here:
   warm       — device-resident: execute immediately
 
 Residency is per device: ``upload(dev_id)`` puts one copy of the weights
-on ``jax.devices()[dev_id]``, the chip the control plane placed the
-invocation on, and ``evict(dev_id)`` drops only that copy. Uploads and
-evictions are real operations with real cost on every backend, so the
-control-plane integration is exercised end to end.
+on ``jax.devices()[dev_id]`` and ``evict(dev_id)`` drops only that copy.
+The endpoint decides nothing: each device's memory manager decides whose
+weights that chip holds, and the wall-clock executor uploads and evicts
+to follow it. Both are real operations with real cost on every backend.
 """
 from __future__ import annotations
 

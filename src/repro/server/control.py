@@ -194,12 +194,12 @@ class ControlPlane:
                 # keep-alive-only baseline: no activation-time uploads,
                 # every transfer starts at dispatch on the critical path
                 dev.mem.anticipatory_upload = self._prefetch_on
-                dev.mem.evict_listeners.append(dp.on_region_evicted)
+                dev.mem.residency_listeners.append(dp.on_residency)
                 if self.fabric is not None:
                     # migrations source through the normal residency
                     # surface: when a source region leaves this HBM,
                     # every migration reading it falls back to host
-                    dev.mem.evict_listeners.append(
+                    dev.mem.residency_listeners.append(
                         self._peer_evict_listener(dev.dev_id))
         T = getattr(policy, "T", 0.0)
         lean = getattr(config, "metrics", "full") == "lean"
@@ -864,16 +864,17 @@ class ControlPlane:
         return best
 
     def _peer_evict_listener(self, src: int):
-        """Evict listener bound to one device's memory manager: when a
-        region leaves that HBM, every migration streaming *from* it
+        """Residency listener bound to one device's memory manager: when
+        a region leaves that HBM, every migration streaming *from* it
         falls back to the destination's host link (restart from byte
         zero, waiters preserved). Uses the destination datapath's
         event-refreshed clock — evictions arrive without a timestamp."""
         fabric = self.fabric
 
-        def on_evict(fn_id):
-            for dst_dp in fabric.on_source_evicted(src, fn_id):
-                dst_dp.peer_source_lost(fn_id, dst_dp.now)
+        def on_evict(fn_id, resident):
+            if not resident:
+                for dst_dp in fabric.on_source_evicted(src, fn_id):
+                    dst_dp.peer_source_lost(fn_id, dst_dp.now)
         return on_evict
 
     def prefetch_pass(self, now: float) -> None:

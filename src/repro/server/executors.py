@@ -29,13 +29,13 @@ import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from repro import obs
 from repro.core.flow import QueueState
 from repro.faults import FaultError
 from repro.runtime.invocation import Invocation
-from repro.server.control import ControlPlane, DispatchDecision
+from repro.server.control import ControlPlane, DeviceState, DispatchDecision
 from repro.server.events import EventBus
 from repro.server.metrics import RunResult, StreamingStats
 
@@ -569,6 +569,22 @@ class SimExecutor:
                          stats=self.stats, util_integral=cp.util_integral)
 
 
+def _throttled_spans(t0: float):
+    """A state-change listener that keeps each queue's stay in THROTTLED
+    as an ``mqfq.throttled`` span (``t0``: the executor's clock origin)."""
+    since: Dict[str, float] = {}
+
+    def on_state_change(ev) -> None:
+        if ev.new is QueueState.THROTTLED:
+            since[ev.fn_id] = ev.time
+        elif ev.old is QueueState.THROTTLED:
+            t = since.pop(ev.fn_id, None)
+            if t is not None:
+                obs.record("mqfq.throttled", None, ev.fn_id, t + t0,
+                           ev.time + t0)
+    return on_state_change
+
+
 class WallClockExecutor:
     """Threaded executor over real endpoints (replaces the old
     ``ServingEngine``), now with the full control plane: multi-device
@@ -588,16 +604,15 @@ class WallClockExecutor:
         self.control = control
         self.endpoints = endpoints
         self.config = config
-        # resolved once: this used to be re-read via getattr on every
-        # dispatcher pass
-        self._batch = getattr(config, "batch_dispatch", True)
+        # batch_dispatch=False: the seed's one decision per pass
+        self._budget = None if getattr(config, "batch_dispatch", True) else 1
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._lock = threading.RLock()
-        # signaled (under _lock) after every completion: drain() waits on
-        # this instead of burning CPU in a sleep/poll loop — the drained
-        # condition (no pending, no inflight) can only become true at a
-        # completion
+        # signaled (under _lock) after every completion and prefetch:
+        # drain() waits on this instead of burning CPU in a sleep/poll
+        # loop — the drained condition (no pending, no inflight) can only
+        # become true at one of them
         self._idle = threading.Condition(self._lock)
         workers = max(config.d * config.n_devices, 1)
         self._pool = ThreadPoolExecutor(max_workers=workers + 1)
@@ -619,48 +634,57 @@ class WallClockExecutor:
         # drain()/stop() re-raise it (once) so a broken run cannot exit
         # clean
         self._error: Optional[BaseException] = None
-        # fn_id -> executor time its queue turned THROTTLED (under _lock)
-        self._throttled_at: Dict[str, float] = {}
-        # control-plane events -> real data movement
         if subscribe_state:
-            control.bus.on_state_change(self._on_state_change)
+            control.bus.on_state_change(_throttled_spans(self._t0))
+        # the memory managers alone place weights; (dev_id, fn_id) ->
+        # device of each region reserved since the last dispatch pass
+        self._reserved: Dict[Tuple[int, str], DeviceState] = {}
         for dev in control.devices:
-            dev.mem.evict_listeners.append(
-                functools.partial(self._on_region_evicted, dev.dev_id))
+            dev.mem.residency_listeners.append(
+                functools.partial(self._on_residency, dev))
 
     # -- time ---------------------------------------------------------------
     def now(self) -> float:
         return time.monotonic() - self._t0
 
     # -- memory integration ----------------------------------------------------
-    def _on_state_change(self, ev) -> None:
-        """Anticipatory prefetch: queue turned Active -> upload weights
-        asynchronously to the function's sticky device, off the critical
-        path (§4.3). A queue's stay in THROTTLED is kept as an
-        ``mqfq.throttled`` span."""
-        if ev.new is QueueState.THROTTLED:
-            self._throttled_at[ev.fn_id] = ev.time
-        elif ev.old is QueueState.THROTTLED:
-            since = self._throttled_at.pop(ev.fn_id, None)
-            if since is not None:
-                obs.record("mqfq.throttled", None, ev.fn_id,
-                           since + self._t0, ev.time + self._t0)
-        ep = self.endpoints.get(ev.fn_id)
-        if ep is None or ev.new is not QueueState.ACTIVE:
-            return
-        dev_id = self.control._fn_device(ev.fn_id).dev_id
-        try:
-            self._pool.submit(self._prefetch, ep, dev_id)
-        except RuntimeError:
-            pass  # pool shutting down: prefetch is best-effort anyway
+    def _on_residency(self, dev: DeviceState, fn_id: str,
+                      resident: bool) -> None:
+        """``dev``'s manager reserved or evicted ``fn_id`` (under
+        ``_lock``): a reservation no dispatch claims is prefetched, an
+        eviction settled now and as invocations leave ``dev``."""
+        if resident:
+            self._reserved[(dev.dev_id, fn_id)] = dev
+        else:
+            self._settle(dev, fn_id)
 
-    def _prefetch(self, ep, dev_id: int) -> None:
+    def _settle(self, dev: DeviceState, fn_id: str) -> None:
+        """Drop ``fn_id``'s copy on ``dev`` unless its region is resident
+        or an invocation of it runs there (callers hold ``_lock``)."""
+        ep = self.endpoints[fn_id]
+        if ep.resident_on(dev.dev_id) and not (
+                dev.running_fn_count.get(fn_id)
+                or dev.mem.is_resident(fn_id, float("inf"))):
+            ep.evict(dev.dev_id)
+
+    def _prefetch(self, dev: DeviceState, fn_id: str) -> None:
+        """Upload ``fn_id``'s weights to ``dev`` off the critical path
+        (§4.3) while its region is reserved there; an eviction that came
+        as the bytes moved is settled after."""
+        ep = self.endpoints[fn_id]
         try:
             with ep.lock:
-                if ep.compiled and not ep.resident_on(dev_id):
-                    ep.upload(dev_id)
+                with self._lock:
+                    wanted = dev.mem.is_resident(fn_id, float("inf"))
+                if wanted and not ep.resident_on(dev.dev_id):
+                    ep.upload(dev.dev_id)
         except Exception as e:  # noqa: BLE001 - surfaced by drain/stop
             self._record_error(e)
+        finally:
+            with self._lock:
+                self._settle(dev, fn_id)
+                self._inflight -= 1
+                self._idle.notify_all()
 
     def _record_error(self, e: BaseException) -> None:
         with self._lock:
@@ -672,19 +696,6 @@ class WallClockExecutor:
         e, self._error = self._error, None
         if e is not None:
             raise e
-
-    def _on_region_evicted(self, dev_id: int, fn_id: str) -> None:
-        """The memory manager of device ``dev_id`` swapped a region out:
-        drop that device's copy of the weights (skip if the function is
-        mid-execution; accounting and reality reconcile at its next
-        dispatch)."""
-        ep = self.endpoints.get(fn_id)
-        if ep is None:
-            return
-        q = self.control.policy.queues.get(fn_id)
-        if q is not None and q.in_flight > 0:
-            return
-        ep.evict(dev_id)
 
     # -- API ------------------------------------------------------------------
     def submit(self, fn_id: str, request: Optional[dict] = None
@@ -709,18 +720,18 @@ class WallClockExecutor:
             self._watchdog.start()
 
     def drain(self, timeout: float = 300.0) -> None:
-        """Block until no work is pending, in flight, or parked for
-        retry. Waits on the completion condition variable (the old
-        implementation polled at 10 ms, burning a core for the length of
-        any long real run). On timeout the executor is torn down — stop
-        event set, dispatcher joined, worker pool released — *before*
-        ``TimeoutError`` propagates, so a wedged run does not leak
-        threads that keep dispatching behind the caller's back."""
+        """Block until no work is pending, in flight (prefetches too), or
+        parked for retry. Waits on the completion condition variable
+        (the old implementation polled at 10 ms, burning a core for the
+        length of any long real run). On timeout the executor is torn
+        down — stop event set, dispatcher joined, worker pool released —
+        *before* ``TimeoutError`` propagates, so a wedged run does not
+        leak threads that keep dispatching behind the caller's back."""
         deadline = time.monotonic() + timeout
         timed_out = False
         with self._idle:
             while (self.control.total_pending != 0 or self._inflight != 0
-                   or self._pending_retries != 0):
+                   or self._pending_retries != 0 or self._reserved):
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     timed_out = True
@@ -813,6 +824,7 @@ class WallClockExecutor:
         with self._lock:
             now = self.now()
             rt = self.control.on_attempt_failed(inv, now, mode)
+            self._settle(self.control.device_state(inv.device_id), inv.fn_id)
             if rt is not None:
                 heapq.heappush(self._retry_heap, (rt, inv.inv_id, inv))
                 self._pending_retries += 1
@@ -830,6 +842,8 @@ class WallClockExecutor:
         inv = decision.inv
         obs.record("inv.queue", inv.inv_id, inv.fn_id, inv.arrival + self._t0,
                    inv.dispatch_time + self._t0)
+        # the worker uploads on its own demand path
+        self._reserved.pop((decision.device.dev_id, inv.fn_id), None)
         self._inflight += 1
         self._pool.submit(self._execute, decision)
 
@@ -838,14 +852,13 @@ class WallClockExecutor:
         invocation under a single lock acquisition instead of re-taking
         the lock (and re-entering the control plane) once per token."""
         with self._lock:
-            if self._batch:
-                return bool(self.control.drain(
-                    self.now(), realize=self._realize_decision))
-            decision = self.control.try_dispatch(self.now())
-            if decision is None:
-                return False
-            self._realize_decision(decision)
-            return True
+            dispatched = bool(self.control.drain(
+                self.now(), self._budget, self._realize_decision))
+            for (_, fn_id), dev in self._reserved.items():
+                self._inflight += 1
+                self._pool.submit(self._prefetch, dev, fn_id)
+            self._reserved.clear()
+            return dispatched
 
     def _execute(self, d: DispatchDecision) -> None:
         """One dispatched invocation on a worker thread. Its spans run on
@@ -934,6 +947,7 @@ class WallClockExecutor:
                         obs.record("inv.complete", iid, fn, done, now + t0)
                     self.completed.append(inv)
                     self.control.on_complete(inv, now)
+                    self._settle(d.device, fn)
                     self.control.sample(now)
                     self._inflight -= 1
                     self._idle.notify_all()
@@ -978,21 +992,11 @@ class ShardedWallClockExecutor:
             self._hash_route = None
         self._stop_evt = threading.Event()
         self._vt_thread: Optional[threading.Thread] = None
-        # one bus subscription for the whole plane: prefetches are
-        # delegated to the owning shard's executor/worker pool
-        sharded.bus.on_state_change(self._on_state_change)
+        # one bus subscription for the whole plane
+        sharded.bus.on_state_change(_throttled_spans(self._t0))
 
     def now(self) -> float:
         return time.monotonic() - self._t0
-
-    def _on_state_change(self, ev) -> None:
-        if self._hash_route is not None:
-            k = self._hash_route(ev.fn_id)
-        else:
-            k = self.sharded.router.assign.get(ev.fn_id)
-            if k is None:
-                k = 0
-        self.execs[k]._on_state_change(ev)
 
     # -- API ------------------------------------------------------------------
     def submit(self, fn_id: str, request: Optional[dict] = None

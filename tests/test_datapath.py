@@ -162,7 +162,7 @@ def _wired(capacity=32 * GB, bw=1 * GB, staging=64 * GB):
     mem = DeviceMemoryManager(capacity, policy="prefetch_swap")
     dp = DeviceDataPath(0, bw, staging, mem)
     mem.uploader = dp.request
-    mem.evict_listeners.append(dp.on_region_evicted)
+    mem.residency_listeners.append(dp.on_residency)
     return mem, dp
 
 
@@ -369,8 +369,8 @@ def test_scalar_datapath_is_bit_identical_to_the_pre_pr_stack():
         srv.bus.on_state_change(lambda ev: states.append(
             (ev.fn_id, ev.old.value, ev.new.value, ev.time)))
         for dev in srv.control.devices:
-            dev.mem.evict_listeners.append(
-                lambda fn, i=dev.dev_id: evicts.append((i, fn)))
+            dev.mem.residency_listeners.append(
+                lambda fn, res, i=dev.dev_id: res or evicts.append((i, fn)))
         res = srv.run_trace(trace)
         summary = (len(res.invocations), res.mean_latency(),
                    res.p99_latency(), res.start_type_counts(),

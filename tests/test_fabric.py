@@ -132,7 +132,7 @@ def _fabric_wired(n=2, capacity=32 * GB, bw=1 * GB, p2p=8 * GB,
     for i in range(n):
         mem = DeviceMemoryManager(capacity, policy="prefetch_swap")
         dp = DeviceDataPath(i, bw, staging, mem, fabric=fabric)
-        mem.evict_listeners.append(dp.on_region_evicted)
+        mem.residency_listeners.append(dp.on_residency)
         mems.append(mem)
         dps.append(dp)
 

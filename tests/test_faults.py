@@ -155,7 +155,7 @@ def _dp(bw=1 * GB):
     mem = DeviceMemoryManager(32 * GB, policy="prefetch_swap")
     dp = DeviceDataPath(0, bw, 64 * GB, mem)
     mem.uploader = dp.request
-    mem.evict_listeners.append(dp.on_region_evicted)
+    mem.residency_listeners.append(dp.on_residency)
     return mem, dp
 
 

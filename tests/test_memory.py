@@ -66,7 +66,8 @@ class TestManager:
             m.acquire(name, 2 * GB, 1.0)     # identical last_use
             m.on_queue_idle(name, 2.0)       # evictable, still resident
         evicts = []
-        m.evict_listeners.append(evicts.append)
+        m.residency_listeners.append(
+            lambda fn, res: res or evicts.append(fn))
         m.acquire("d", 4 * GB, 3.0)
         assert evicts == ["a", "b"]
 

@@ -8,7 +8,8 @@ Three altitudes:
   1. Op-level fuzz: scripted pseudo-random op sequences (with deliberate
      timestamp collisions, so every LRU tie-break is exercised) driven
      through both implementations, comparing every return value, the
-     eviction-callback sequence, residency snapshots and byte counters —
+     sequence of residency announcements (every reservation and
+     eviction), residency snapshots and byte counters —
      across all four memory policies.
   2. Control-plane replays under memory pressure: full traces through
      ``repro.server`` with ``device_layer="indexed"``+batched drain vs
@@ -41,8 +42,9 @@ def drive_manager(cls, mem_policy: str, seed: int):
     """Scripted op sequence; returns every observable the manager has."""
     rng = random.Random(seed)
     m = cls(capacity_bytes=8 * GB, h2d_bw=4 * GB, policy=mem_policy)
-    evicts = []
-    m.evict_listeners.append(evicts.append)
+    announced = []          # every reservation and eviction, in order
+    m.residency_listeners.append(
+        lambda fn, res: announced.append((fn, res)))
     fns = [f"f{i}" for i in range(24)]
     sizes = {f: (1 + i % 5) * (GB // 2) for i, f in enumerate(fns)}
     log = []
@@ -70,7 +72,7 @@ def drive_manager(cls, mem_policy: str, seed: int):
                     tuple(f2 for f2 in fns if m.is_resident(f2, t))))
     log.append(("totals", m.bytes_uploaded, m.bytes_evicted,
                 m.prefetch_count))
-    return evicts, log
+    return announced, log
 
 
 @pytest.mark.parametrize("mem_policy", MEM_POLICIES)
@@ -78,7 +80,7 @@ def drive_manager(cls, mem_policy: str, seed: int):
 def test_manager_op_equivalence(mem_policy, seed):
     fast = drive_manager(make_device_layer("indexed")[0], mem_policy, seed)
     ref = drive_manager(make_device_layer("reference")[0], mem_policy, seed)
-    assert fast[0] == ref[0], "eviction sequences diverged"
+    assert fast[0] == ref[0], "residency announcements diverged"
     for i, (a, b) in enumerate(itertools.zip_longest(fast[1], ref[1])):
         assert a == b, f"op #{i} diverged: indexed={a} reference={b}"
 
@@ -90,7 +92,8 @@ def drive_second_pass(cls):
     that bug-for-bug."""
     m = cls(capacity_bytes=6 * GB, h2d_bw=100 * GB, policy="prefetch")
     evicts = []
-    m.evict_listeners.append(evicts.append)
+    m.residency_listeners.append(
+        lambda fn, res: res or evicts.append(fn))
     m.acquire("a", 1 * GB, 0.0)      # will become the lone evictable
     m.acquire("b", 2 * GB, 1.0)      # stays non-evictable (never idled)
     m.acquire("c", 2 * GB, 2.0)
@@ -183,8 +186,8 @@ def replay(trace_name, *, policy="mqfq-sticky", policy_kwargs=None,
     srv.bus.on_state_change(lambda ev: states.append(
         (ev.fn_id, ev.old.value, ev.new.value, ev.time)))
     for dev in srv.control.devices:
-        dev.mem.evict_listeners.append(
-            lambda fn, i=dev.dev_id: evicts.append((i, fn)))
+        dev.mem.residency_listeners.append(
+            lambda fn, res, i=dev.dev_id: res or evicts.append((i, fn)))
     res = srv.run_trace(TRACES[trace_name])
     summary = {
         "n": len(res.invocations),
@@ -275,7 +278,8 @@ def _wallclock_run(device_layer: str):
     srv.bus.on_dispatch(lambda ev: log.append(
         (ev.fn_id, ev.device_id, ev.start_type)))
     dev = srv.control.devices[0]
-    dev.mem.evict_listeners.append(evicts.append)
+    dev.mem.residency_listeners.append(
+        lambda fn, res: res or evicts.append(fn))
     srv.start()
     for f in ["f0", "f1", "f2"] * 4:
         srv.submit(f, {"seed": 0})

@@ -146,14 +146,15 @@ PLACEMENT_SCRIPT = textwrap.dedent("""
         assert out["weight_devices"] == {devs[inv.device_id]}, inv
         assert (out["tokens"] == want[(inv.fn_id, inv.request["seed"])]).all()
 
-    # the memory manager's evict listeners are bound per device
+    # an eviction by one device's memory manager drops only that copy
     fn = res.invocations[0].fn_id
     ep = eps[fn]
     for d in (0, 1):
         if not ep.resident_on(d):
             ep.upload(d)
-    for cb in srv.control.devices[1].mem.evict_listeners:
-        cb(fn)
+    mem = srv.control.devices[1].mem
+    mem.acquire(fn, srv.control.fns[fn].mem_bytes, 0.0)
+    mem.drop_region(fn)
     assert ep.resident_on(0) and not ep.resident_on(1)
     print("PLACEMENT_OK")
 """)

@@ -27,7 +27,8 @@ def _pressured(strict: bool) -> DeviceMemoryManager:
     m = DeviceMemoryManager(10 * GB, policy="prefetch",
                             strict_reclaim=strict)
     log = []
-    m.evict_listeners.append(log.append)
+    m.residency_listeners.append(
+        lambda fn, res: res or log.append(fn))
     m.acquire("A", 3 * GB, 1.0)
     m.acquire("B", 3 * GB, 2.0)
     m.acquire("C", 3 * GB, 3.0)
@@ -64,7 +65,8 @@ def test_strict_matches_reference_bug_for_bug():
     broad surface; this pins the quirk itself)."""
     ref = ReferenceDeviceMemoryManager(10 * GB, policy="prefetch")
     log = []
-    ref.evict_listeners.append(log.append)
+    ref.residency_listeners.append(
+        lambda fn, res: res or log.append(fn))
     ref.acquire("A", 3 * GB, 1.0)
     ref.acquire("B", 3 * GB, 2.0)
     ref.acquire("C", 3 * GB, 3.0)
